@@ -183,6 +183,8 @@ def main() -> None:
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help="JSON record path ('' disables)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     if args.smoke:
         args.episodes = min(args.episodes, 30)
 

@@ -18,6 +18,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import paper
     from benchmarks import roofline as rf
 

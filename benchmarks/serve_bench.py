@@ -1059,6 +1059,8 @@ def main() -> None:
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help="JSON record path ('' disables)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     rows, (cfg, model, sparams) = bench(args)
     print("name,tokens_per_s,derived")

@@ -1,2 +1,1 @@
-"""ReLeQ reproduction package.  Importing installs jax compat shims."""
-from repro import compat as _compat  # noqa: F401
+"""ReLeQ reproduction package."""

@@ -11,27 +11,27 @@ Dispatch policy (``REPRO_PALLAS`` env var):
 - ``on``: force compiled Pallas (real TPU runs).
 
 The wrappers own all shape normalization: flattening batch dims, padding to
-tile multiples, slicing back.
+tile multiples, slicing back.  Under a mesh (``jax.set_mesh``) they also
+place each Pallas call with :func:`_on_mesh`, since XLA cannot partition a
+Mosaic kernel.
 """
 from __future__ import annotations
 
+import functools
+import math
 import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as kref
 from repro.kernels.fake_quant import fake_quant_pallas
 from repro.kernels.fused_decode import fused_qkv_paged_decode_pallas
 from repro.kernels.paged_attention import (paged_attention_pallas,
                                            paged_attention_quant_pallas)
-from repro.kernels.qmm import qmm_pallas
+from repro.kernels.qmm import DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, qmm_pallas
 from repro.quant.wrpn import tensor_scale
-
-_INTERPRET_ELEM_CAP = 1 << 22  # don't interpret-execute tiles beyond ~4M elems
-# fused decode keeps all three packed projection weights resident in VMEM;
-# past this budget fall back to the unfused pipeline (qmm + paged attention)
-_FUSED_VMEM_CAP = 8 << 20
 
 
 def _mode() -> str:
@@ -42,10 +42,30 @@ def _mode() -> str:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def _on_mesh(kernel, rows: tuple[bool, ...], *args):
+    """``kernel(*args)``, a Pallas call, placed on the ambient mesh.
+
+    Off a mesh (or inside a ``shard_map``, where each device already holds
+    its own operands) it is called as is.  On a mesh it runs in a
+    ``shard_map``: the arguments flagged in ``rows`` and every output are
+    split along their leading (row) axis over the mesh's batch axes when
+    that axis divides, and the other arguments — weights, and the KV pool,
+    whose block tables hold global block ids — are gathered whole onto
+    each device.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return kernel(*args)
+    batch = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    n = math.prod(mesh.shape[a] for a in batch)
+    split = [a.shape[0] for a, r in zip(args, rows) if r]
+    spec = P(batch) if batch and split and split[0] % n == 0 else P()
+    return jax.shard_map(kernel, mesh=mesh, check_vma=False,
+                         in_specs=tuple(spec if r else P() for r in rows),
+                         out_specs=spec)(*args)
 
 
 def _pad_to(x: jax.Array, mults: tuple[int, ...]) -> jax.Array:
@@ -70,7 +90,9 @@ def fake_quant(w: jax.Array, bits, scale=None) -> jax.Array:
     M, N = w2.shape
     bm, bn = min(256, M), min(256, N)
     w2p = _pad_to(w2, (bm, bn))  # pad up to tile multiples, slice back below
-    out = fake_quant_pallas(w2p, bits, scale, block=(bm, bn), interpret=interpret)
+    out = _on_mesh(functools.partial(fake_quant_pallas, block=(bm, bn),
+                                     interpret=interpret),
+                   (False, False, False), w2p, bits, scale)
     out = out[:M, :N]
     return out.reshape(shape)
 
@@ -107,13 +129,17 @@ def paged_attention(
         return out.astype(q.dtype)
     interpret = mode == "interpret"
     if quantized:
-        out = paged_attention_quant_pallas(
+        out = _on_mesh(
+            functools.partial(paged_attention_quant_pallas,
+                              interpret=interpret),
+            (True, False, False, False, False, True, True),
             q.reshape(B, KV, G, hd), k_pool, v_pool, k_scale, v_scale,
-            block_tables, lengths, interpret=interpret)
+            block_tables, lengths)
     else:
-        out = paged_attention_pallas(
-            q.reshape(B, KV, G, hd), k_pool, v_pool, block_tables, lengths,
-            interpret=interpret)
+        out = _on_mesh(
+            functools.partial(paged_attention_pallas, interpret=interpret),
+            (True, False, False, True, True),
+            q.reshape(B, KV, G, hd), k_pool, v_pool, block_tables, lengths)
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
@@ -136,8 +162,9 @@ def fused_qkv_paged_decode(
     v_sc)`` — codes/scales for the new token, which the caller scatters
     into the pool (write-then-attend ≡ the kernel's attend-with-splice).
 
-    TPU path is ONE kernel (``kernels.fused_decode``) when the packed
-    planes fit the VMEM budget; otherwise, and off-TPU, the composed
+    TPU path is ONE kernel (``kernels.fused_decode``); the caller checks
+    ``fused_decode.fits_vmem`` first and takes the unfused qmm + paged
+    attention pair when the planes do not fit.  Off-TPU, the composed
     oracle (bitwise the unfused qmm + rope + quantize + attend chain).
     """
     B, D = x.shape
@@ -145,9 +172,7 @@ def fused_qkv_paged_decode(
     packed4 = k_pool.dtype == jnp.uint8
     hd = k_pool.shape[-1] * 2 if packed4 else k_pool.shape[-1]
     mode = _mode()
-    w_bytes = sum(p.planes.size for p in (wq, wk, wv))
-    fits = w_bytes <= _FUSED_VMEM_CAP
-    if mode == "ref" or (mode == "auto" and not (_on_tpu() and fits)):
+    if mode == "ref" or (mode == "auto" and not _on_tpu()):
         out, kc, vc, ks, vs = kref.fused_qkv_paged_decode_ref(
             x, wq, wk, wv, k_pool, v_pool, k_scale, v_scale, block_tables,
             lengths, qmax, rope_theta, H, KV)
@@ -158,12 +183,14 @@ def fused_qkv_paged_decode(
 
     inv = rope_freqs(hd, rope_theta)                          # (hd/2,)
     ang = lengths.astype(jnp.float32)[:, None] * inv          # (B, hd/2)
-    out, kc, vc, ks, vs = fused_qkv_paged_decode_pallas(
+    out, kc, vc, ks, vs = _on_mesh(
+        functools.partial(fused_qkv_paged_decode_pallas, bits_q=wq.bits,
+                          bits_k=wk.bits, bits_v=wv.bits, num_heads=H,
+                          interpret=interpret),
+        (True,) + (False,) * 10 + (True,) * 4 + (False,),
         x, wq.planes, wq.scale, wk.planes, wk.scale, wv.planes, wv.scale,
         k_pool, v_pool, k_scale, v_scale, block_tables, lengths,
-        jnp.cos(ang), jnp.sin(ang), qmax,
-        bits_q=wq.bits, bits_k=wk.bits, bits_v=wv.bits, num_heads=H,
-        interpret=interpret)
+        jnp.cos(ang), jnp.sin(ang), jnp.asarray(qmax, jnp.float32))
     return out.reshape(B, 1, H, hd).astype(x.dtype), kc, vc, ks, vs
 
 
@@ -195,24 +222,18 @@ def qmm(
         out = kref.qmm_ref(x2, packed, scale, bits)
         return out.astype(out_dtype).reshape(*batch, N)
     interpret = mode == "interpret"
-    # tile alignment: pick divisors, pad M (cheap) rather than K/N (packed)
-    bm = _pick_block(M, 128, pad_ok=True)
-    bn = _pick_block(N, 256)
-    bk = _pick_block(K, 512, multiple_of=8)
-    x2p = _pad_to(x2, (bm, 1))
-    out = qmm_pallas(
-        x2p, packed, scale.reshape(1, N), bits=bits, path=path,
-        block=(bm, bn, bk), interpret=interpret, out_dtype=out_dtype,
-    )
-    return out[:M].reshape(*batch, N)
 
+    def run(x2, packed, scale):
+        # pad M (cheap) to its tile; N and K take partial edge tiles
+        # (qmm.py).  On a mesh this runs on each device's rows.
+        m = x2.shape[0]
+        bm = min(m, DEFAULT_BM)
+        out = qmm_pallas(
+            _pad_to(x2, (bm, 1)), packed, scale, bits=bits, path=path,
+            block=(bm, DEFAULT_BN, DEFAULT_BK),
+            interpret=interpret, out_dtype=out_dtype,
+        )
+        return out[:m]
 
-def _pick_block(dim: int, target: int, multiple_of: int = 1, pad_ok: bool = False) -> int:
-    """Largest divisor of ``dim`` ≤ target that's a multiple of multiple_of;
-    if pad_ok, just return min(target, next multiple) and let caller pad."""
-    if pad_ok:
-        return min(target, dim) if dim >= target else dim
-    b = min(target, dim)
-    while b > 1 and (dim % b or b % multiple_of):
-        b -= 1
-    return max(b, 1)
+    out = _on_mesh(run, (True, False, False), x2, packed, scale.reshape(1, N))
+    return out.reshape(*batch, N)

@@ -19,6 +19,13 @@ finite -1e30 mask) — the paged-vs-slot parity contract.
 int4) with per-(token, KV-head) scales: each DMA'd block is dequantized
 in VMEM — ``codes.f32 * scale`` — so HBM traffic shrinks by the code
 width (2-4x vs bf16) and the dequantized values never round-trip HBM.
+
+Nibble-packed blocks are never re-interleaved in VMEM (a lane shape cast
+Mosaic refuses).  Byte ``m`` holds head dims ``2m`` (low nibble) and
+``2m+1`` (high nibble), so the wrapper hands the kernel ``q`` split into
+its even and odd head dims on a leading axis: ``q·k = q_even·lo +
+q_odd·hi``, and ``p @ v`` comes out as the same two halves, which the
+wrapper interleaves back.
 """
 from __future__ import annotations
 
@@ -29,7 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.quant.pack import kv_unpack_int4
 
 _NEG = -1e30
 
@@ -114,6 +120,12 @@ def paged_attention_pallas(
       q, k_pool, v_pool)
 
 
+def _nibbles(codes):
+    """(…, n) uint8 nibble pairs -> (low, high) signed codes as f32."""
+    u = codes.astype(jnp.int32)
+    return ((u & 0x0F) - 8).astype(jnp.float32), ((u >> 4) - 8).astype(jnp.float32)
+
+
 def _paged_attn_quant_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
                              vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
                              bs: int, scale: float, packed4: bool):
@@ -126,15 +138,20 @@ def _paged_attn_quant_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                  # (KV, G, hd)
-    kc, vc = k_ref[0], v_ref[0]                       # (bs, KV, hd[/2])
+    ks = ks_ref[0][..., None]                         # (bs, KV, 1)
+    vs = vs_ref[0][..., None]
+    # dequantize in VMEM: codes * per-(token, head) scale.  q and the
+    # accumulator carry a leading axis of head-dim parts: one part for
+    # int8, (even, odd) for nibble-packed int4
     if packed4:
-        kc, vc = kv_unpack_int4(kc), kv_unpack_int4(vc)
-    # dequantize in VMEM: codes * per-(token, head) scale
-    k = kc.astype(jnp.float32) * ks_ref[0][..., None]
-    v = vc.astype(jnp.float32) * vs_ref[0][..., None]
-    s = jnp.einsum("kgh,tkh->kgt", q, k,
-                   preferred_element_type=jnp.float32) * scale
+        k_parts = [c * ks for c in _nibbles(k_ref[0])]
+        v_parts = [c * vs for c in _nibbles(v_ref[0])]
+    else:
+        k_parts = [k_ref[0].astype(jnp.float32) * ks]
+        v_parts = [v_ref[0].astype(jnp.float32) * vs]
+    s = sum(jnp.einsum("kgh,tkh->kgt", q_ref[0, i].astype(jnp.float32), k,
+                       preferred_element_type=jnp.float32)
+            for i, k in enumerate(k_parts)) * scale
     pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
     mask = pos < len_ref[b]
     s = jnp.where(mask, s, _NEG)
@@ -144,8 +161,9 @@ def _paged_attn_quant_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
     corr = jnp.exp(m_old - m_new)
     m_ref[...] = m_new
     l_ref[...] = l_old * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum(
-        "kgt,tkh->kgh", p, v, preferred_element_type=jnp.float32)
+    for i, v in enumerate(v_parts):
+        acc_ref[i] = acc_ref[i] * corr[..., None] + jnp.einsum(
+            "kgt,tkh->kgh", p, v, preferred_element_type=jnp.float32)
 
     @pl.when(j == nb - 1)
     def _():
@@ -177,11 +195,15 @@ def paged_attention_quant_pallas(
     packed4 = k_pool.dtype == jnp.uint8
     assert KV == KVk and hds == (hd // 2 if packed4 else hd), (
         q.shape, k_pool.shape)
+    parts = 2 if packed4 else 1
+    # (B, parts, KV, G, hds): part i holds head dims i, i + parts, ...
+    qp = q.reshape(B, KV, G, hds, parts).transpose(0, 4, 1, 2, 3)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, KV, G, hd), lambda b, j, bt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, parts, KV, G, hds),
+                         lambda b, j, bt, ln: (b, 0, 0, 0, 0)),
             pl.BlockSpec((1, bs, KV, hds),
                          lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
             pl.BlockSpec((1, bs, KV, hds),
@@ -191,22 +213,23 @@ def paged_attention_quant_pallas(
             pl.BlockSpec((1, bs, KV),
                          lambda b, j, bt, ln: (bt[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, KV, G, hd),
-                               lambda b, j, bt, ln: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, parts, KV, G, hds),
+                               lambda b, j, bt, ln: (b, 0, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((KV, G), jnp.float32),
             pltpu.VMEM((KV, G), jnp.float32),
-            pltpu.VMEM((KV, G, hd), jnp.float32),
+            pltpu.VMEM((parts, KV, G, hds), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_attn_quant_kernel, bs=bs,
                                scale=hd ** -0.5, packed4=packed4)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, parts, KV, G, hds), jnp.float32),
         interpret=interpret,
         name=f"paged_decode_attention_{'int4' if packed4 else 'int8'}",
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      q, k_pool, v_pool, k_scale.astype(jnp.float32),
+      qp, k_pool, v_pool, k_scale.astype(jnp.float32),
       v_scale.astype(jnp.float32))
+    return out.transpose(0, 2, 3, 4, 1).reshape(B, KV, G, hd)

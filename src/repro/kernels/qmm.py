@@ -20,15 +20,26 @@ Both paths share the oracle :func:`repro.kernels.ref.qmm_ref`.
 
 Layout notes
 ------------
-- packed: ``(bits, K//8, N) uint8`` — N minor-most (lane axis), so the
-  unpack broadcast `(K//8, 8, N)` keeps lanes contiguous and the
-  `(K//8, 8, N) -> (K, N)` reshape is a sublane relayout Mosaic supports.
-  The 8× sublane expansion is amortized over a (bm × bn) MXU tile.
+- packed: ``(bits, K//8, N) uint8`` — N minor-most (lane axis); byte row
+  ``j`` holds contraction rows ``8j..8j+7`` (row ``8j+i`` in bit ``i``).
+- The kernels never interleave the 8 bit-rows of a byte back into one
+  ``(K, N)`` plane (a sublane shape cast Mosaic refuses).  The wrapper
+  instead permutes the *activation*: ``x`` arrives as ``(8, M, K//8)``
+  with ``xs[i, :, j] = x[:, 8j+i]``, so ``x @ plane_b = Σ_i xs[i] @
+  ((packed[b] >> i) & 1)`` — one 2-D shift + mask per bit row, each a
+  plain MXU matmul over ``K//8``.  The permutation is a tiny XLA
+  transpose of the activation (M ≤ a few hundred rows).
 - The k-grid accumulates into a VMEM f32 scratch; output is written on the
   last k step (revisited-output pattern), with the per-column scale applied
   once at the end.
-- Tile defaults: (bm, bn, bk) = (128, 256, 512) → x tile 128·512·2 B=128 KiB,
-  packed tile ≤ 8·64·256 B = 128 KiB, acc 128 KiB — comfortably in VMEM
+- Tiles: (bm, bn, bk) = (128, 256, 2048), each clipped to its axis.
+  ``bk // 8`` and ``bn`` are then multiples of 128 (the lane width) or
+  the whole axis, and the grid takes ``cdiv`` steps, so any N and K tile
+  without padding the packed weights: a partial last N tile writes only
+  its in-bounds columns, and a partial last K tile reads plane rows past
+  ``K // 8`` (undefined on the chip) against activation columns that are
+  zero-padded, so they add nothing.  x tile 8·128·256·2 B = 512 KiB,
+  packed tile ≤ 8·256·256 B = 512 KiB, acc 128 KiB — comfortably in VMEM
   with double buffering.
 """
 from __future__ import annotations
@@ -40,18 +51,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in newer pallas; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 256, 512
+DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 256, 2048
 
 
-def _unpack_tile(p, bits: int):
-    """(bits, bk//8, bn) uint8 -> (bits, bk, bn) int32 in {0,1}."""
-    shifts = jnp.arange(8, dtype=jnp.uint8)[None, None, :, None]
-    bit = (p[:, :, None, :] >> shifts) & jnp.uint8(1)  # (bits, bk//8, 8, bn)
-    b, k8, _, n = bit.shape
-    return bit.reshape(b, k8 * 8, n).astype(jnp.int32)
+def bit_rows(plane, i: int):
+    """Bit ``i`` of every byte of a ``(rows, n)`` uint8 plane tile, as
+    int32 {0, 1}: row ``j`` holds contraction row ``8j + i``."""
+    return (plane.astype(jnp.int32) >> i) & 1
+
+
+def split_rows(x):
+    """``(M, K)`` activation -> ``(8, M, K//8)`` with ``[i, :, j] = x[:, 8j+i]``
+    — the layout in which :func:`bit_rows` of a packed plane multiplies."""
+    M, K = x.shape
+    return x.reshape(M, K // 8, 8).transpose(2, 0, 1)
 
 
 def _qmm_dequant_kernel(x_ref, p_ref, s_ref, o_ref, acc_ref, *, bits, nk):
@@ -61,14 +74,16 @@ def _qmm_dequant_kernel(x_ref, p_ref, s_ref, o_ref, acc_ref, *, bits, nk):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    planes = _unpack_tile(p_ref[...], bits)  # (bits, bk, bn) {0,1}
     n_lvl = 2 ** (bits - 1) - 1 if bits > 1 else 1
-    u = planes[0]
-    for b in range(1, bits):  # static unroll: Σ_b plane_b << b
-        u = u + (planes[b] << b)
-    w = (u - n_lvl).astype(jnp.bfloat16)  # signed codes, one tile
-    x = x_ref[...].astype(jnp.bfloat16)
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    acc = acc_ref[...]
+    for i in range(8):  # static unroll over the bit rows of each byte
+        u = bit_rows(p_ref[0], i)
+        for b in range(1, bits):  # Σ_b plane_b << b
+            u = u + (bit_rows(p_ref[b], i) << b)
+        w = (u - n_lvl).astype(jnp.float32).astype(jnp.bfloat16)
+        x = x_ref[i].astype(jnp.bfloat16)
+        acc += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
 
     @pl.when(k == nk - 1)
     def _finish():
@@ -83,15 +98,18 @@ def _qmm_bitserial_kernel(x_ref, p_ref, s_ref, o_ref, acc_ref, off_ref, *, bits,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         off_ref[...] = jnp.zeros_like(off_ref)
 
-    x = x_ref[...].astype(jnp.bfloat16)
-    planes = _unpack_tile(p_ref[...], bits)  # (bits, bk, bn)
     acc = acc_ref[...]
-    for b in range(bits):  # static unroll: one binary MXU matmul per plane
-        pb = planes[b].astype(jnp.bfloat16)
-        acc += float(1 << b) * jnp.dot(x, pb, preferred_element_type=jnp.float32)
+    off = off_ref[...]
+    for i in range(8):  # static unroll: bit rows x planes binary matmuls
+        x = x_ref[i].astype(jnp.bfloat16)
+        for b in range(bits):
+            pb = bit_rows(p_ref[b], i).astype(jnp.float32).astype(jnp.bfloat16)
+            acc += float(1 << b) * jnp.dot(x, pb,
+                                           preferred_element_type=jnp.float32)
+        # rank-1 offset: n_lvl · rowsum(x), accumulated over the K grid
+        off += jnp.sum(x.astype(jnp.float32), axis=1, keepdims=True)
     acc_ref[...] = acc
-    # rank-1 offset: n_lvl · rowsum(x), accumulated over the K grid
-    off_ref[...] += jnp.sum(x.astype(jnp.float32), axis=1, keepdims=True)
+    off_ref[...] = off
 
     @pl.when(k == nk - 1)
     def _finish():
@@ -116,18 +134,21 @@ def qmm_pallas(
 ) -> jax.Array:
     """y[M,N] = x[M,K] @ dequant(packed[bits,K//8,N], scale[1,N]).
 
-    Shapes must be tile-aligned (ops.qmm pads).  ``bits`` static (the packed
-    buffer's plane count is structural).
+    M must be a multiple of its tile (ops.qmm pads it); N and K need not
+    be (module notes).  ``bits`` static (the packed buffer's plane count
+    is structural).
     """
     M, K = x.shape
     bts, K8, N = packed.shape
     if bts != bits or K8 * 8 != K:
         raise ValueError(f"packed {packed.shape} inconsistent with x {x.shape}, bits={bits}")
     bm, bn, bk = (min(block[0], M), min(block[1], N), min(block[2], K))
-    if M % bm or N % bn or K % bk or bk % 8:
-        raise ValueError(f"shape {(M, K, N)} not divisible by block {(bm, bn, bk)}")
-    nk = K // bk
-    grid = (M // bm, N // bn, nk)
+    if M % bm or bk % 8:
+        raise ValueError(f"shape {(M, K, N)} not tileable by block {(bm, bn, bk)}")
+    bk8 = bk // 8
+    nk = pl.cdiv(K8, bk8)
+    grid = (M // bm, pl.cdiv(N, bn), nk)
+    x = jnp.pad(x, ((0, 0), (0, nk * bk - K)))
     kernel = _qmm_dequant_kernel if path == "dequant" else _qmm_bitserial_kernel
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     if path == "bitserial":
@@ -136,16 +157,16 @@ def qmm_pallas(
         functools.partial(kernel, bits=bits, nk=nk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bits, bk // 8, bn), lambda i, j, k: (0, k, j)),
+            pl.BlockSpec((8, bm, bk8), lambda i, j, k: (0, i, k)),
+            pl.BlockSpec((bits, bk8, bn), lambda i, j, k: (0, k, j)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name=f"qmm_{path}_{bits}b",
-    )(x, packed, scale)
+    )(split_rows(x), packed, scale)

@@ -136,6 +136,8 @@ def main():
                     help="structured logs as JSON lines instead of text")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     if args.log_json:
         from repro.obs import configure
         configure(json_mode=True)

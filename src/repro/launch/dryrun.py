@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # fake devices only; never open a chip
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -144,8 +145,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, serve_bits: int = 4,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()  # XLA:CPU: while bodies counted ONCE
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     costs = analyze_hlo(compiled.as_text())  # loop-corrected (see hlo_analysis)
     rl = roofline_from_costs(costs, chips=chips, model_flops=model_flops)
 

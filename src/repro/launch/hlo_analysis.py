@@ -16,8 +16,9 @@ This module parses the *optimized* HLO text instead:
    fusions/calls ×= 1,
 4. per computation, accumulate
    - dot/conv flops (2 × numel(out) × contraction size),
-   - HBM traffic ≈ Σ over top-level instructions of (operands + output)
-     bytes — post-fusion instruction boundaries approximate real traffic,
+   - HBM traffic ≈ Σ over top-level instructions of 2 × output bytes —
+     post-fusion instruction boundaries approximate real traffic — plus
+     each ENTRY parameter's bytes once (the weights and cache read in),
    - collective payload bytes by kind,
    each scaled by the computation's multiplier.
 
@@ -111,6 +112,16 @@ def parse_hlo(text: str) -> dict[str, Computation]:
             cur.instrs.append((var, shape, op, rest))
             cur.shapes[var] = shape
     return comps
+
+
+_TPU_KERNEL = re.compile(
+    r"%([\w\-]+?)(?:\.\d+)?\s*=.*custom_call_target=\"tpu_custom_call\"")
+
+
+def tpu_kernels(text: str) -> set[str]:
+    """Names of the Pallas kernels compiled into a TPU executable's HLO
+    (each ``pallas_call`` becomes a ``tpu_custom_call`` named after it)."""
+    return set(_TPU_KERNEL.findall(text))
 
 
 def _while_trip_count(cond: Computation) -> int:
@@ -246,6 +257,11 @@ def analyze_hlo(text: str) -> HLOCosts:
                 costs.trip_counts[var] = (
                     int(mt.group(1)) if mt else _while_trip_count(
                         comps.get(mc.group(1), Computation(""))) if mc else 1)
+            if op == "parameter" and name == entry:
+                # the program's inputs (weights, cache) are read from HBM
+                # once; a consumer that fuses their unpack in produces
+                # nothing of their size, so the output rule never sees them
+                costs.traffic_bytes += _shape_bytes(shape)
             if op in ("dot", "convolution"):
                 costs.flops += m_ * _dot_flops(shape, rest, comp.shapes)
             for kind in _COLLECTIVE_KINDS:

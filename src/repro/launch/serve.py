@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.quant.policy import QuantPolicy
 from repro.quant.qat import policy_for
@@ -60,22 +61,24 @@ from repro.serve import SamplingParams, ServeEngine
 from repro.train.serve import make_decode_step, quantize_for_serving
 
 
-def _build(args):
-    cfg = get_config(args.arch, smoke=args.smoke)
+def build(cfg, *, bits: int = 4, policy_json=None, ckpt_dir=None):
+    """Model for ``cfg``, its params (restored from ``ckpt_dir``, else
+    random from seed 0) packed for serving, and the policy that packed
+    them (``policy_json``, else ``bits`` with the frozen groups at 8)."""
     model = build_model(cfg)
-    if args.ckpt_dir:
+    if ckpt_dir:
         from repro import ckpt as ckpt_lib
 
-        tree, _, step = ckpt_lib.restore(args.ckpt_dir)
+        tree, _, step = ckpt_lib.restore(ckpt_dir)
         params = jax.tree.map(jnp.asarray, tree["params"])
-        print(f"restored step {step} from {args.ckpt_dir}")
+        print(f"restored step {step} from {ckpt_dir}")
     else:
         params = model.init(jax.random.PRNGKey(0))
-    if args.policy_json:
-        policy = QuantPolicy.from_file(args.policy_json)
+    if policy_json:
+        policy = QuantPolicy.from_file(policy_json)
     else:
-        policy = policy_for(model, default_bits=args.bits)
-    return cfg, model, quantize_for_serving(model, params, policy), policy
+        policy = policy_for(model, default_bits=bits)
+    return model, quantize_for_serving(model, params, policy), policy
 
 
 def _static(args, cfg, model, sparams, policy):
@@ -276,10 +279,14 @@ def main():
                     help="structured logs as JSON lines instead of text")
     args = ap.parse_args()
 
+    compile_cache.enable()
     if args.log_json:
         from repro.obs import configure
         configure(json_mode=True)
-    cfg, model, sparams, policy = _build(args)
+    cfg = get_config(args.arch, smoke=args.smoke)
+    model, sparams, policy = build(cfg, bits=args.bits,
+                                   policy_json=args.policy_json,
+                                   ckpt_dir=args.ckpt_dir)
     if args.mode == "static":
         _static(args, cfg, model, sparams, policy)
     else:

@@ -43,6 +43,8 @@ def main():
                          "sharded training + elastic checkpoint restore")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     mesh = None
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split(","))

@@ -14,16 +14,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import ambient_mesh
-
 # ---------------------------------------------------------------------------
 # sharding helpers
 # ---------------------------------------------------------------------------
 
 
 def _mesh_axes() -> tuple[str, ...]:
-    m = ambient_mesh()
-    return tuple(m.axis_names) if m is not None and not m.empty else ()
+    m = jax.sharding.get_abstract_mesh()
+    return () if m.empty else tuple(m.axis_names)
 
 
 def shard_profile() -> str:
@@ -87,8 +85,8 @@ def constrain(x: jax.Array, *spec) -> jax.Array:
     1-device smoke mesh, the single-pod and the multi-pod mesh, and on archs
     whose head counts don't divide the model axis (e.g. glm4 kv=2).
     """
-    m = ambient_mesh()
-    if m is None or m.empty:
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty:
         return x
     sizes = dict(zip(m.axis_names, m.axis_sizes))
     clean = []

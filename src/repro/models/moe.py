@@ -132,10 +132,8 @@ def moe_ffn(
     the layer scan (hundreds of GB/device; EXPERIMENTS.md §Perf).
     Meshless (smoke tests / CPU search): local per-sequence dispatch.
     """
-    from repro.compat import ambient_mesh
-
-    mesh = ambient_mesh()
-    if mesh is not None and not mesh.empty and "model" in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty and "model" in mesh.axis_names:
         return _moe_ep(x, p, k=k, capacity_factor=capacity_factor,
                        no_drop=no_drop, mesh=mesh)
     B, S, D = x.shape

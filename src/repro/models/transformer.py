@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import fused_decode
 from repro.kernels import ops as kops
 from repro.quant.pack import (QDQ, Packed, kv_dequantize, kv_pack_int4,
                               kv_qdq, kv_quantize, kv_unpack_int4)
@@ -156,10 +157,11 @@ class TransformerLM:
         B, S, D = x.shape
         H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        wq, wk, wv = (p["attn"][m] for m in ("wq", "wk", "wv"))
         if (cache is not None and S == 1 and "k_scale" in cache
                 and window is None and cfg.rope == "rope"
-                and all(isinstance(p["attn"][m], Packed)
-                        for m in ("wq", "wk", "wv"))):
+                and all(isinstance(w, Packed) for w in (wq, wk, wv))
+                and fused_decode.fits_vmem(wq, wk, wv)):
             out = self._fused_decode_attn(h, p, cache, layer)
             out = out.reshape(B, S, H * hd)
             out = apply_linear(out, p["attn"]["wo"])
@@ -194,8 +196,9 @@ class TransformerLM:
             sub = slot % bs
             eff_len = jnp.minimum(length + 1, Tc)
             if "k_scale" in cache:
-                # quantized blocks (unfused path: windowed attention or
-                # unpacked weights): quantize the new token, scatter codes
+                # quantized blocks (unfused Pallas pair: windowed attention,
+                # unpacked weights, or packed q/k/v planes above the fused
+                # kernel's VMEM budget): quantize the new token, scatter codes
                 # + per-(token, head) scales, attend with in-place dequant
                 qmax = cache["kv_qmax"][layer]
                 k_codes, k_sc = kv_quantize(k[:, 0], qmax)

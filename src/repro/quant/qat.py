@@ -102,15 +102,13 @@ def quantize_params(params, bits_map: dict[str, jax.Array], groups):
 
     Under an ambient mesh (``jax.set_mesh``) each leaf's QDQ is annotated
     with its ``dist/sharding.py`` rule-table spec — see ``_qdq``."""
-    from repro.compat import ambient_mesh
     from repro.models.common import shard_profile
 
     idx = _paths_index(groups)
-    mesh = ambient_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     # dp profile: the step body runs inside shard_map (manual axes), where
     # sharding constraints are illegal — and pointless, params replicate
-    use_mesh = (mesh is not None and not mesh.empty
-                and shard_profile() != "dp")
+    use_mesh = not mesh.empty and shard_profile() != "dp"
     new = params
     for key, bits in bits_map.items():
         path = idx[key]

@@ -87,9 +87,16 @@ Guarantees
 Sharding: pass ``mesh=`` and the pool is placed over the mesh's data
 axes via ``repro.dist`` — the paged pool's *block* axis sits where the
 slot axis did, so ``cache_specs`` covers both (parity pinned in
-``tests/test_distributed.py::test_sharded_pool_parity``).  Admission is
-still a single-host decision; making it collective across hosts is the
-recorded ROADMAP follow-up.
+``tests/test_distributed.py::test_sharded_pool_parity``).  The engine
+runs each step under its mesh and XLA partitions it.  XLA cannot
+partition a Pallas kernel, so ``kernels/ops`` places each in a
+``shard_map``: rows split over the data axes, weights and the KV pool
+gathered whole onto every device.  A partitioned step rounds differently from a one-device step:
+its logits agree to a tolerance, not bitwise, and greedy streams may
+part at a near-tie (pinned in
+``tests/test_distributed.py::test_sharded_paged_step_matches_one_device``).
+Admission is still a single-host decision; making it collective across
+hosts is the recorded ROADMAP follow-up.
 
 Known limits (ROADMAP "Open items"): no beam search / logit bias,
 single-host admission, no block sharing between sequences (prefix

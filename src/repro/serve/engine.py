@@ -110,9 +110,11 @@ attribute check per call site (<= 3%% tokens/s, gated in
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -170,7 +172,9 @@ class ServeEngine:
         self.obs = registry if registry is not None else Registry()
         self.tracer = tracer if tracer is not None else NULL_TRACER
         # mesh != None places the KV pool over the mesh's data axes
-        # (repro.dist sharding hook) — decode updates stay shard-local
+        # (repro.dist sharding hook), and every step runs under the mesh,
+        # which XLA partitions over it (see step())
+        self.mesh = mesh
         if cache == "paged":
             self.pool = PagedCachePool(model, num_slots, max_len,
                                        block_size=block_size,
@@ -402,7 +406,16 @@ class ServeEngine:
         """One engine iteration.  Returns the step's events:
         ``{"admitted": [ids], "tokens": [(id, tok)], "finished": [ids],
         "preempted": [ids]}``.
+
+        With ``mesh=`` the step runs under ``jax.set_mesh(mesh)`` whatever
+        the caller's context, so the model's sharding constraints (and
+        MoE expert parallelism) always see the engine's mesh.
         """
+        with (jax.set_mesh(self.mesh) if self.mesh is not None
+              else contextlib.nullcontext()):
+            return self._step()
+
+    def _step(self) -> dict:
         t0 = time.perf_counter()
         tr = self.tracer
         events = {"admitted": [], "tokens": [], "finished": [],

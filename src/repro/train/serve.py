@@ -17,6 +17,8 @@ without materializing a single weight.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -26,11 +28,17 @@ from repro.quant.qat import get_by_path, path_key, set_by_path
 from repro.quant.wrpn import FP_BITS
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _pack_jit(w, bits: int):
+    # one fused program: run op by op, the (bits, K, N) uint32 bit planes
+    # of a 4096 x 151552 head at 8 bits would take 20 GB of device memory
+    return pack_weight(w.astype(jnp.float32), bits)
+
+
 def _pack_matrix(w, bits: int):
     if bits >= 16:  # not worth packing; serve bf16
         return w
-    planes, scale = pack_weight(w.astype(jnp.float32), bits)
-    return Packed(planes, scale, bits)
+    return Packed(*_pack_jit(w, bits), bits)
 
 
 def _pack_bank(w, bits: int):

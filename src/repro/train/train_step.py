@@ -102,7 +102,7 @@ def make_dp_train_step(model, optimizer: AdamW, mesh, *, axis: str = "data",
             local, mesh=mesh,
             in_specs=(P(), P(), P(axis), P(axis), P()),
             out_specs=(P(), P(), P(axis), P()),
-            check_vma=False,  # compat shim maps this onto 0.4's check_rep
+            check_vma=False,
         )(state["params"], state["opt"], state["ef"], batch, bits_map)
         return {"params": new_p, "opt": new_o, "ef": new_ef}, out
 

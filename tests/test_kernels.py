@@ -38,9 +38,12 @@ def test_fake_quant_kernel(shape, dtype, bits):
 @pytest.mark.parametrize("bits", [2, 3, 4, 8])
 @pytest.mark.parametrize("path", ["dequant", "bitserial"])
 def test_qmm_kernel(mkn, bits, path):
-    M, K, N = mkn
-    w = jnp.asarray(RNG.normal(size=(K, N)), jnp.float32)
-    x = jnp.asarray(RNG.normal(size=(M, K)), jnp.float32)
+    _check_qmm_kernel(RNG, *mkn, bits, path)
+
+
+def _check_qmm_kernel(rng, M, K, N, bits, path):
+    w = jnp.asarray(rng.normal(size=(K, N)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
     planes, scale = pack_weight(w, bits)
     want = kref.qmm_ref(x, planes, scale, bits)
     got = qmm_pallas(x, planes, scale.reshape(1, N), bits=bits, path=path,
@@ -48,6 +51,34 @@ def test_qmm_kernel(mkn, bits, path):
                      interpret=True)
     rel = float(jnp.max(jnp.abs(got - want))) / float(jnp.max(jnp.abs(want)))
     assert rel < 2e-2, rel  # bf16 MXU accumulation tolerance
+
+
+# The partial-tile tests draw from their own generators, so the shared RNG
+# stream (and with it every later test's data) stays as it was.
+@pytest.mark.parametrize("bits", [2, 3, 4, 8])
+@pytest.mark.parametrize("path", ["dequant", "bitserial"])
+def test_qmm_kernel_partial_tiles(bits, path):
+    """K//8 = 40 plane rows in tiles of 16 and N = 200 columns in tiles of
+    128: the last tile of both axes is partial."""
+    _check_qmm_kernel(np.random.default_rng(11), 8, 320, 200, bits, path)
+
+
+@pytest.mark.parametrize("path", ["dequant", "bitserial"])
+def test_qmm_ops_partial_tiles(monkeypatch, path):
+    """``ops.qmm`` tiles at its defaults: K//8 = 257 plane rows and N = 300
+    columns each end in a partial tile, and M = 130 pads to two row tiles."""
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    rng = np.random.default_rng(12)
+    M, K, N = 130, 2056, 300
+    w = jnp.asarray(rng.normal(size=(K, N)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
+    planes, scale = pack_weight(w, 4)
+    want = kref.qmm_ref(x, planes, scale, 4)
+    got = ops.qmm(x, planes, scale, bits=4, path=path)
+    rel = float(jnp.max(jnp.abs(got - want))) / float(jnp.max(jnp.abs(want)))
+    assert rel < 2e-2, rel
 
 
 @pytest.mark.parametrize("xdtype", [jnp.float32, jnp.bfloat16])
