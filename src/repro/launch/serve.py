@@ -37,11 +37,15 @@ sampling but dispatches synchronously; the summary line reports which
 path ran plus the lookahead/bubble counts.
 
 Observability (``repro.obs``): ``--trace out.json`` records the full
-request lifecycle (queue wait, prefill chunks, decode device/host
-split, spec windows, preempt/COW/evict instants) as a Chrome-trace
-file loadable at ui.perfetto.dev; ``--metrics-interval N`` prints the
-engine's registry snapshot every N steps; ``--log-json`` switches all
-structured logs to JSON lines.
+request lifecycle (queue wait, engine steps, admissions with their
+prefill chunks, logits fetch and first-token choice, decode dispatch /
+wait / host split, spec windows, preempt/COW/evict instants) as a
+Chrome-trace file loadable at ui.perfetto.dev; ``--metrics-interval N``
+logs one ``snapshot`` line every N steps (tokens, tokens/s, the
+engine's queued and running request counts, recompiles);
+``--log-json`` switches all structured logs to JSON lines.  Run under
+``jax.profiler.trace(dir)`` to get the same spans beside the device's
+ops on one timeline.
 """
 from __future__ import annotations
 
@@ -269,12 +273,14 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="continuous mode: record a Chrome-trace of the "
-                         "run (queue wait, prefill chunks, decode "
-                         "device/host split, spec windows, preempt/COW/"
-                         "evict instants) — open at ui.perfetto.dev")
+                         "run (queue wait, engine steps, admissions, "
+                         "prefill chunks, decode dispatch/wait/host "
+                         "split, spec windows, preempt/COW/evict "
+                         "instants) — open at ui.perfetto.dev")
     ap.add_argument("--metrics-interval", type=int, default=0,
-                    help="continuous mode: log a registry snapshot line "
-                         "every N engine steps (0 = off)")
+                    help="continuous mode: log a snapshot line (tokens, "
+                         "tokens/s, queued and running requests, "
+                         "recompiles) every N engine steps (0 = off)")
     ap.add_argument("--log-json", action="store_true",
                     help="structured logs as JSON lines instead of text")
     args = ap.parse_args()

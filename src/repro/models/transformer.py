@@ -299,7 +299,8 @@ class TransformerLM:
         else:
             emb = params["embed"]
             if isinstance(emb, QDQ):  # serving embed: quantize at lookup
-                emb = wrpn_fake_quant(emb.w, emb.bits, axis=0)
+                with jax.named_scope("embed_quant"):
+                    emb = wrpn_fake_quant(emb.w, emb.bits, axis=0)
             h = jnp.take(emb, tokens, axis=0)
         return constrain(h, batch_axes(), None, None)
 
@@ -359,10 +360,12 @@ class TransformerLM:
         if w is None:
             emb = params["embed"]
             if isinstance(emb, QDQ):
-                emb = wrpn_fake_quant(emb.w, emb.bits, axis=0)
+                with jax.named_scope("embed_quant"):
+                    emb = wrpn_fake_quant(emb.w, emb.bits, axis=0)
             w = emb.T
         h = constrain(h, readout_axes(), None, None)  # tokens off the model axis
-        logits = apply_linear(h, w).astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = apply_linear(h, w).astype(jnp.float32)
         return constrain(logits, readout_axes(), None, "model")
 
     def loss(self, params, batch, remat: str = "none"):

@@ -1,6 +1,7 @@
-"""repro.obs: zero-dependency observability for the search->serve pipeline.
+"""repro.obs: observability for the search->serve pipeline.
 
-Three small, composable layers (stdlib + numpy only):
+Three small, composable layers (stdlib + numpy; the tracer also opens
+``jax.profiler`` annotations while a profiler session records):
 
 - :mod:`repro.obs.core` — a thread-safe :class:`Registry` of typed
   instruments (:class:`Counter`, :class:`Gauge`, :class:`Histogram` with
@@ -10,8 +11,10 @@ Three small, composable layers (stdlib + numpy only):
   records;
 - :mod:`repro.obs.trace` — a bounded ring-buffer span :class:`Tracer`
   (``span()`` context manager, ``instant()`` events, ``complete()`` for
-  retro-dated durations) that is near-zero cost when disabled and
-  exports Chrome-trace / Perfetto JSON;
+  retro-dated durations) that is near-zero cost when disabled,
+  exports Chrome-trace / Perfetto JSON, and puts its spans on the JAX
+  profiler's timeline beside the device's ops whenever a profiler
+  session records;
 - :mod:`repro.obs.log` — rate-limited structured logging
   (:func:`get_logger`, ``--log-json`` on the launchers switches every
   logger to one-JSON-object-per-line via :func:`configure`).

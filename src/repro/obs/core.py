@@ -4,8 +4,8 @@ Three instrument kinds cover everything the pipeline reports:
 
 - :class:`Counter` — monotone float/int accumulator (``inc``).  Tokens
   emitted, prefill launches, COW copies, recompiles, preemptions.
-- :class:`Gauge` — last-write-wins level (``set``).  Queue depth,
-  running rows, free blocks, archive size.
+- :class:`Gauge` — last-write-wins level (``set``).  The autotune
+  archive size.
 - :class:`Histogram` — fixed-boundary bucket counts plus, when
   ``window=N`` is given, an exact bounded sample window whose
   ``percentile()`` reproduces ``np.percentile`` over the last ``N``

@@ -1,4 +1,5 @@
-"""Span tracer: bounded ring buffer -> Chrome-trace / Perfetto JSON.
+"""Span tracer: bounded ring buffer -> Chrome-trace / Perfetto JSON, and
+the JAX profiler's own timeline whenever a profiler session records.
 
 Usage::
 
@@ -12,11 +13,21 @@ Usage::
 
 Design constraints (the serve loop calls this per decode step):
 
-- **near-zero cost when disabled**: ``span()`` returns one shared
-  no-op context manager (no allocation), ``instant``/``complete``
-  return immediately — the only per-call cost is an attribute check.
-  The serve bench gates tracing-enabled throughput at <= 3% of
-  disabled.
+- **on the profiler's clock**: while a ``jax.profiler`` session records
+  (``jax.profiler.trace(dir)``, ``start_trace``), ``span()`` and
+  ``instant()`` also open a ``jax.profiler.TraceAnnotation`` of the same
+  name and args — an instant is a zero-length one, ``set()`` forwards
+  through ``set_metadata``.  The profiler records these itself, so they
+  share the device trace's clock and the device ops under them can be
+  read off one timeline.  This holds whether or not the ring is enabled:
+  the shared ``NULL_TRACER`` appears in every profiler trace.
+  ``complete()`` stays ring-only (the profiler cannot back-date a span).
+- **near-zero cost when disabled**: with the ring disabled and no
+  profiler session, ``span()`` returns one shared no-op context manager
+  (no allocation), ``instant``/``complete`` return immediately — the
+  per-call cost is an attribute check and one
+  ``TraceAnnotation.is_enabled()`` call.  The serve bench gates
+  tracing-enabled throughput at <= 3% of disabled.
 - **bounded**: events live in a ``deque(maxlen=capacity)`` — a
   long-lived engine can trace forever and keep the newest ``capacity``
   events; ``dropped`` counts what the ring discarded.
@@ -30,8 +41,8 @@ Design constraints (the serve loop calls this per decode step):
   workers show up as their own Perfetto tracks); ``deque.append`` is
   atomic under the GIL, so recording never takes a lock.
 
-Timestamps are ``time.perf_counter()`` microseconds relative to the
-tracer's construction, matching the engine's latency clocks.
+Ring timestamps are ``time.perf_counter()`` microseconds relative to
+the tracer's construction, matching the engine's latency clocks.
 """
 from __future__ import annotations
 
@@ -39,6 +50,10 @@ import json
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
+
+_recording = TraceAnnotation.is_enabled   # a profiler session is recording
 
 
 class _NullSpan:
@@ -60,33 +75,46 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0", "_tid")
+    """One open span: a ring event (``tracer`` enabled), a profiler
+    annotation (``ann``, a session recording), or both."""
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
+    __slots__ = ("_tracer", "name", "args", "_t0", "_tid", "_ann")
+
+    def __init__(self, tracer: "Tracer | None", name: str, args: dict,
+                 ann: TraceAnnotation | None):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._ann = ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         tr = self._tracer
-        self._tid = threading.get_ident()
-        tr._depth[self._tid] = tr._depth.get(self._tid, 0) + 1
-        self._t0 = time.perf_counter()
+        if tr is not None:
+            self._tid = threading.get_ident()
+            tr._depth[self._tid] = tr._depth.get(self._tid, 0) + 1
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
-        tr = self._tracer
-        tr._depth[self._tid] -= 1
         if exc_type is not None:
-            self.args["error"] = exc_type.__name__
-        tr._push(self.name, "X", self._t0, t1 - self._t0, self._tid,
-                 self.args)
+            self.set(error=exc_type.__name__)
+        tr = self._tracer
+        if tr is not None:
+            tr._depth[self._tid] -= 1
+            tr._push(self.name, "X", self._t0, t1 - self._t0, self._tid,
+                     self.args)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
     def set(self, **args) -> None:
         """Attach/overwrite args before the span closes."""
         self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
 
 class Tracer:
@@ -122,13 +150,19 @@ class Tracer:
 
     def span(self, name: str, **args):
         """Context manager timing one operation.  Nested spans render as
-        Perfetto stack frames on the recording thread's track."""
+        Perfetto stack frames on the recording thread's track, and as
+        nested annotations in a recording profiler session."""
+        ann = TraceAnnotation(name, **args) if _recording() else None
         if not self.enabled:
-            return NULL_SPAN
-        return _Span(self, name, args)
+            return NULL_SPAN if ann is None else _Span(None, name, args, ann)
+        return _Span(self, name, args, ann)
 
     def instant(self, name: str, **args) -> None:
-        """Zero-duration marker event."""
+        """Zero-duration marker event (a zero-length annotation in a
+        recording profiler session)."""
+        if _recording():
+            with TraceAnnotation(name, **args):
+                pass
         if not self.enabled:
             return
         self._push(name, "i", time.perf_counter(), 0.0,
@@ -136,7 +170,8 @@ class Tracer:
 
     def complete(self, name: str, start: float, dur: float, **args) -> None:
         """Retro-dated span from explicit ``perf_counter`` seconds — e.g.
-        queue wait recorded at admission, dated back to arrival."""
+        queue wait recorded at admission, dated back to arrival.  Ring
+        only: a profiler annotation cannot be back-dated."""
         if not self.enabled:
             return
         self._push(name, "X", start, max(dur, 0.0),

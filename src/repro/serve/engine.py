@@ -67,8 +67,8 @@ On top of that sits a ONE-STEP-LOOKAHEAD pipeline.  Timeline, one row::
         step t+1 is dispatched BEFORE step t's tokens are fetched, so
         the fetch + Python bookkeeping of step t overlap step t+1's
         device compute.  Steady-state host work is off the critical
-        path; ``decode.device`` (the blocking token fetch) absorbs the
-        wait and ``decode.host`` shrinks toward zero.
+        path; ``decode.wait`` (the host blocked on the token fetch)
+        absorbs the wait and ``decode.host`` shrinks toward zero.
 
     The lookahead only launches when the next step is *composition-
     stable*: nothing queued to admit, every running request has budget
@@ -98,15 +98,22 @@ is the JSON view) — ``metrics()`` is rebuilt on the registry with
 byte-compatible keys and the same ``metrics_window`` sliding-window
 percentile semantics.  A :class:`~repro.obs.Tracer` (``tracer=``)
 records the full request lifecycle as Chrome-trace spans: queue wait
-(retro-dated to enqueue), admission with prefix hit/replay counts,
-every prefill chunk, each decode step split into **device time**
-(dispatch + logits fetch) vs **host overhead** (sampling/bookkeeping),
-speculative draft/verify/fix-up phases with per-window acceptance,
-preempt instants, pool COW/eviction/flush instants, and an
-``xla.compile`` instant whenever a jit cache grows (``_cache_size``
-delta — steady state must show zero).  Disabled tracing costs one
-attribute check per call site (<= 3%% tokens/s, gated in
-``benchmarks/serve_bench.py``).  See ``docs/metrics.md``.
+(retro-dated to enqueue), each engine step (``serve.step``), admission
+with prefix hit/replay counts, every prefill chunk, the host's wait for
+the prefill's logits (``admit.fetch``) and its choice of the first
+token (``admit.sample``), each decode step split into dispatch, the
+host blocked on the result fetch (``decode.wait``) and host bookkeeping
+(``decode.host``), speculative draft/verify/fix-up phases with
+per-window acceptance, preempt instants, pool COW/eviction/flush
+instants, and an ``xla.compile`` instant whenever a jit cache grows
+(``_cache_size`` delta — steady state must show zero).  While a
+``jax.profiler`` session records, every span and instant is also a
+profiler annotation on the device trace's clock — also with the default
+disabled tracer — so each idle stretch of the device can be charged to
+the host phase open over it.  Disabled tracing costs one attribute
+check and one ``TraceAnnotation.is_enabled()`` per call site (<= 3%%
+tokens/s, gated in ``benchmarks/serve_bench.py``).  See
+``docs/metrics.md``.
 """
 from __future__ import annotations
 
@@ -258,8 +265,14 @@ class ServeEngine:
                                             window=metrics_window)
         self._h_shared = obs.histogram("prefix.blocks_shared", unit="blocks",
                                        buckets=TOK, window=metrics_window)
-        self._g_queue = obs.gauge("serve.queue_depth", unit="requests")
-        self._g_running = obs.gauge("serve.running_rows", unit="rows")
+        # the host's choice of each admission's first token: admissions
+        # that chose one, and the seconds spent choosing (``admit.sample``)
+        self._c_first_tokens = obs.counter(
+            "serve.first_tokens", unit="requests",
+            desc="admissions that chose a first token")
+        self._c_first_token_s = obs.counter(
+            "serve.first_token_host_seconds", unit="s",
+            desc="host time choosing admissions' first tokens")
         self._c_spec_windows = obs.counter("spec.windows")
         self._c_spec_proposed = obs.counter("spec.proposed", unit="tokens")
         self._c_spec_accepted = obs.counter("spec.accepted", unit="tokens")
@@ -361,7 +374,9 @@ class ServeEngine:
             self.pool.write(slot, cache1)
         self._note_exec("prefill", self._prefill)
         self._c_prefill_launches.inc()
-        return req.select_token(np.asarray(logits)[0, -1]), len(req.prompt), True
+        with self.tracer.span("admit.fetch", request=req.request_id):
+            row = np.asarray(logits)[0, -1]  # blocks until prefill lands
+        return self._first_token(req, row), len(req.prompt), True
 
     def _admit_paged(self, req: Request, seq: int, hit: int = 0):
         """Chunked prefill straight into the sequence's blocks.  Every
@@ -399,7 +414,21 @@ class ServeEngine:
         self._h_admit_total.observe(len(replay))
         if req.output_tokens:  # resume: last emitted token is the next feed
             return req.output_tokens[-1], len(replay), False
-        return req.select_token(np.asarray(logits)[0, 0]), len(replay), True
+        with self.tracer.span("admit.fetch", request=req.request_id):
+            row = np.asarray(logits)[0, 0]  # blocks until prefill lands
+        return self._first_token(req, row), len(replay), True
+
+    def _first_token(self, req: Request, row: np.ndarray) -> int:
+        """Choose an admission's first token on the host from its fetched
+        ``(V,)`` logits row, counted in ``serve.first_tokens`` and timed
+        in ``serve.first_token_host_seconds``."""
+        t = time.perf_counter()
+        with self.tracer.span("admit.sample", request=req.request_id,
+                              temperature=req.sampling.temperature):
+            tok = req.select_token(row)
+        self._c_first_token_s.inc(time.perf_counter() - t)
+        self._c_first_tokens.inc()
+        return tok
 
     # ----------------------------------------------------------------- loop
     def step(self) -> dict:
@@ -412,7 +441,8 @@ class ServeEngine:
         MoE expert parallelism) always see the engine's mesh.
         """
         with (jax.set_mesh(self.mesh) if self.mesh is not None
-              else contextlib.nullcontext()):
+              else contextlib.nullcontext()), \
+                self.tracer.span("serve.step", step=self._step_idx):
             return self._step()
 
     def _step(self) -> dict:
@@ -441,7 +471,9 @@ class ServeEngine:
         #    since its dispatch are admitted NOW, not one step late;
         #    _pipeline_tail sees the composition change and bubbles
         #    instead of chaining the newcomer a garbage feed)
-        for req, slot, hit in self.scheduler.admissions():
+        with tr.span("admit.schedule"):
+            admissions = self.scheduler.admissions()
+        for req, slot, hit in admissions:
             wait = time.perf_counter() - req.queued_time
             self._h_queue_wait.observe(wait)
             tr.complete("queue.wait", start=req.queued_time, dur=wait,
@@ -473,7 +505,9 @@ class ServeEngine:
             # 2) reserve next-token blocks; exhaustion preempts youngest
             #    (spec mode reserves per-window inside _spec_step instead)
             if self.cache_kind == "paged" and self.spec is None:
-                for req in self.scheduler.reserve_for_decode():
+                with tr.span("decode.reserve"):
+                    preempted = self.scheduler.reserve_for_decode()
+                for req in preempted:
                     events["preempted"].append(req.request_id)
                     tr.instant("preempt", request=req.request_id,
                                step=self._step_idx)
@@ -486,8 +520,6 @@ class ServeEngine:
                                    else "decode")
 
         self._step_idx += 1
-        self._g_queue.set(len(self.queue))
-        self._g_running.set(self.scheduler.num_running)
         self._c_run_seconds.inc(time.perf_counter() - t0)
         return events
 
@@ -497,8 +529,8 @@ class ServeEngine:
         Attribution (documented in docs/metrics.md): ``_device_seconds``
         is time spent DRIVING OR AWAITING the device — jit dispatch
         (``decode.dispatch`` span; a near-zero enqueue on async backends,
-        the compute itself on synchronous ones) plus the blocking
-        token-vector fetch (``decode.device`` span) — and
+        the compute itself on synchronous ones) plus the host blocked on
+        the token-vector fetch (``decode.wait`` span) — and
         ``decode.host`` is the rest of the step wall time: the Python
         serving loop (sampling on the legacy path, emit/advance
         bookkeeping, table uploads).  The pipelined loop times dispatch
@@ -586,7 +618,7 @@ class ServeEngine:
         sync while the lookahead was already running) carried a phantom
         token, which is discarded here."""
         t_dev = time.perf_counter()
-        with self.tracer.span("decode.device", rows=len(inf.rows)):
+        with self.tracer.span("decode.wait", rows=len(inf.rows)):
             toks = np.asarray(inf.tokens)  # blocks until compute lands
         self._device_seconds += time.perf_counter() - t_dev
         with self.tracer.span("decode.host"):
@@ -664,7 +696,7 @@ class ServeEngine:
         for slot, seq in self.scheduler.running.items():
             toks[slot, 0] = seq.last_token
         t_dev = time.perf_counter()
-        with self.tracer.span("decode.device",
+        with self.tracer.span("decode.wait",
                               rows=len(self.scheduler.running)):
             logits, cache = self._decode(
                 self.sparams, self.pool.step_cache(), jnp.asarray(toks))

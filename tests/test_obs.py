@@ -1,10 +1,14 @@
 """Observability gates: registry instruments + concurrent-snapshot
 consistency (hypothesis), windowed percentile exactness, tracer span
 balance under exceptions / preemption / spec rejection on a REAL engine,
-Chrome-trace schema validation, near-zero disabled cost, engine
+Chrome-trace schema validation, near-zero disabled cost, spans on the
+JAX profiler's timeline (also from a disabled tracer), the engine's
+first-token counters, named scopes in the serving HLO, engine
 ``metrics()`` key compatibility, and structured-log rate limiting."""
+import glob
 import io
 import json
+import os
 import threading
 
 import jax
@@ -240,6 +244,72 @@ def test_chrome_export_schema_and_thread_names(tmp_path):
     assert json.load(open(path)) == doc
 
 
+# ------------------------------------------------ the profiler's timeline
+def _profile(logdir):
+    """A profiler session over ``logdir`` with host annotations only (no
+    Python function tracer)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(logdir), profiler_options=opts)
+
+
+def _host_events(logdir) -> list[tuple[str, int, int, dict]]:
+    """``(name, start_ns, end_ns, args)`` of every host event the profiler
+    recorded under ``logdir``."""
+    paths = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1, paths
+    data = jax.profiler.ProfileData.from_file(paths[0])
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_disabled_tracer_spans_reach_the_profiler(tmp_path):
+    """A disabled tracer's spans and instants become profiler annotations,
+    with their args (``set()`` included) and nesting, while a session
+    records; the ring stays empty, ``complete()`` stays out of the
+    profile, and outside a session ``span()`` is the shared no-op."""
+    tr = Tracer(enabled=False)
+    assert tr.span("a", x=1) is NULL_SPAN
+    with _profile(tmp_path):
+        with tr.span("outer", request=3) as sp:
+            with tr.span("inner"):
+                tr.instant("mark", k=7)
+            sp.set(hit=5)
+        tr.complete("retro", start=0.0, dur=1.0)
+        with pytest.raises(RuntimeError):
+            with tr.span("failing"):
+                raise RuntimeError("boom")
+    assert tr.span("a") is NULL_SPAN and NULL_TRACER.span("b") is NULL_SPAN
+    assert tr.num_events == 0 and tr.depth() == 0
+    ev = {e[0]: e for e in _host_events(tmp_path)}
+    assert ev["outer"][3] == {"request": 3, "hit": 5}
+    assert ev["mark"][3] == {"k": 7}
+    assert ev["failing"][3] == {"error": "RuntimeError"}
+    assert _inside(ev["inner"], ev["outer"]) and _inside(ev["mark"], ev["inner"])
+    assert ev["mark"][2] - ev["mark"][1] < ev["inner"][2] - ev["inner"][1]
+    assert "retro" not in ev
+
+
+def test_enabled_tracer_records_ring_and_profiler_alike(tmp_path):
+    tr = Tracer(enabled=True)
+    with _profile(tmp_path):
+        with tr.span("decode.step", step=2) as sp:
+            sp.set(tokens=4)
+    ring = tr.events("decode.step")[0]
+    prof = [e for e in _host_events(tmp_path) if e[0] == "decode.step"]
+    assert ring["args"] == {"step": 2, "tokens": 4}
+    assert len(prof) == 1 and prof[0][3] == ring["args"]
+    # the two clocks time the same stretch
+    assert prof[0][2] - prof[0][1] == pytest.approx(ring["dur_s"] * 1e9,
+                                                    rel=0.5, abs=2e5)
+
+
 # ------------------------------------------------- engine span balance
 @pytest.fixture(scope="module")
 def glm4():
@@ -294,8 +364,8 @@ def test_engine_spans_balance_under_preemption_and_spec(glm4):
     assert m["spec"]["proposed"] > m["spec"]["accepted"]  # rejections hit
     assert tr.depth() == 0                        # balanced by construction
     names = {e["name"] for e in tr.events()}
-    for want in ("queue.wait", "admit", "prefill.chunk", "decode.step",
-                 "decode.device", "decode.host", "spec.draft",
+    for want in ("queue.wait", "serve.step", "admit", "prefill.chunk",
+                 "decode.step", "decode.wait", "decode.host", "spec.draft",
                  "spec.verify", "spec.resolve", "preempt"):
         assert want in names, want
     assert all(e["dur_s"] >= 0 for e in tr.events())
@@ -325,6 +395,92 @@ def test_engine_spans_balance_on_admission_failure(glm4):
     assert tr.depth() == 0
     assert eng.pool.num_free_blocks == eng.pool.num_blocks - 1  # no leak
     _validate_chrome(tr.to_chrome())
+
+
+# what each engine span nests in (docs/metrics.md, "Serve lifecycle spans")
+ENGINE_PARENTS = {"admit.schedule": "serve.step", "admit": "serve.step",
+                  "prefill.chunk": "admit", "prefill.full": "admit",
+                  "admit.fetch": "admit", "admit.sample": "admit",
+                  "decode.reserve": "serve.step", "decode.step": "serve.step",
+                  "decode.dispatch": "decode.step",
+                  "decode.wait": "decode.step", "decode.host": "decode.step"}
+
+
+@pytest.mark.parametrize("cache", ["paged", "slot"])
+def test_engine_spans_on_the_profiler_timeline(glm4, tmp_path, cache):
+    """The engine's default (disabled) tracer puts every host phase of a
+    step on the profiler's timeline, each inside its parent, and counts
+    the first tokens it chose."""
+    from repro.serve import SamplingParams, ServeEngine
+
+    cfg, model, sparams, fns = glm4
+    fns = ({k: fns[k] for k in ("prefill_fn", "decode_fn")}
+           if cache == "paged" else {"decode_fn": fns["decode_fn"]})
+    eng = ServeEngine(model, sparams, num_slots=2, max_len=32, cache=cache,
+                      block_size=4, prefill_chunk=4, **fns)
+    rids = [eng.submit(_prompt(cfg, 6, seed=s), max_new_tokens=5,
+                       sampling=SamplingParams(temperature=0.8 * (s % 2),
+                                               top_p=0.9, seed=s))
+            for s in range(3)]
+    with _profile(tmp_path):
+        eng.run_until_drained()
+    assert all(len(eng.output(r)) == 5 for r in rids)
+    events = [e for e in _host_events(tmp_path)
+              if e[0] in set(ENGINE_PARENTS) | {"serve.step"}]
+    names = {e[0] for e in events}
+    want = set(ENGINE_PARENTS) - ({"prefill.full"} if cache == "paged"
+                                  else {"prefill.chunk", "decode.reserve"})
+    assert want <= names, want - names
+    by_name: dict = {}
+    for e in events:
+        by_name.setdefault(e[0], []).append(e)
+    for e in events:
+        parent = ENGINE_PARENTS.get(e[0])
+        if parent is not None:
+            assert any(_inside(e, p) for p in by_name[parent]), e
+    assert len(by_name["serve.step"]) == eng.steps
+    assert len(by_name["admit.sample"]) == len(rids)
+    assert {e[3]["request"] for e in by_name["admit.sample"]} == set(rids)
+    assert eng.obs.get("serve.first_tokens").value == len(rids)
+    assert eng.obs.get("serve.first_token_host_seconds").value > 0
+
+
+def test_first_tokens_count_only_admissions_that_chose_one(glm4):
+    """A preempted request re-admits by replay and chooses no new first
+    token: ``serve.first_tokens`` counts requests, not admissions."""
+    from repro.serve import ServeEngine
+
+    cfg, model, sparams, fns = glm4
+    fns = {k: fns[k] for k in ("prefill_fn", "decode_fn")}
+    eng = ServeEngine(model, sparams, num_slots=4, max_len=16, cache="paged",
+                      block_size=4, num_blocks=9, prefill_chunk=4, **fns)
+    rids = [eng.submit(_prompt(cfg, 4, seed=s), max_new_tokens=8)
+            for s in range(4)]
+    eng.run_until_drained()
+    assert all(len(eng.output(r)) == 8 for r in rids)
+    assert eng.metrics()["preemptions"] > 0
+    admitted = eng.obs.get("sched.admitted").value
+    assert eng.obs.get("serve.first_tokens").value == len(rids) < admitted
+
+
+def test_serving_hlo_names_embed_quant_and_lm_head(glm4):
+    """The embedding's fake-quant and the head carry named scopes into the
+    decode step's and the prefill chunk's HLO metadata, so a profile
+    names the device ops they become."""
+    import jax.numpy as jnp
+
+    from repro.serve.cache import PagedCachePool
+
+    cfg, model, sparams, fns = glm4
+    pool = PagedCachePool(model, 2, 16, block_size=4, num_blocks=9)
+    cache = pool.step_cache()
+    dec = fns["decode_fn"].lower(sparams, cache, jnp.zeros((2, 1), jnp.int32))
+    pre = fns["prefill_fn"].lower(sparams, cache, jnp.zeros((1, 4), jnp.int32),
+                                  0, 0, 3)
+    for lowered in (dec, pre):
+        hlo = lowered.compile().as_text()
+        for scope in ("embed_quant", "lm_head"):
+            assert f"/{scope}/" in hlo, scope
 
 
 def test_engine_metrics_keys_unchanged(glm4):
