@@ -53,10 +53,11 @@ Architecture (one file per concern)
   distribution-identical to non-speculative serving.
 
 Decode attends by block table through ``kernels.ops.paged_attention``: a
-Pallas kernel whose BlockSpec index map IS the block table (each live
-block DMA'd exactly once, scalar-prefetched — ``kernels/
-paged_attention.py``), with a gather + ``decode_attention`` oracle in
-``kernels/ref.py`` as the CPU path.
+Pallas kernel that folds several pages of a row into each grid step, its
+page operands indexed through the scalar-prefetched block table, and
+skips the steps past a row's length (each live block copied once —
+``kernels/paged_attention.py``), with a gather + ``decode_attention``
+oracle in ``kernels/ref.py`` as the CPU path.
 
 Use
 ---

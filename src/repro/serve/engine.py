@@ -240,6 +240,9 @@ class ServeEngine:
         self._c_run_seconds = obs.counter("serve.run_seconds", unit="s")
         self._c_occ_sum = obs.counter("serve.occupancy_sum")
         self._c_block_occ_sum = obs.counter("serve.block_occupancy_sum")
+        self._c_attn_live_pages = obs.counter(
+            "serve.attn_live_pages", unit="pages",
+            desc="KV pages the running rows' decode attention reads")
         self._c_prefill_launches = obs.counter("serve.prefill_launches")
         self._c_recompiles = obs.counter(
             "serve.recompiles", desc="jit cache growth after construction")
@@ -539,6 +542,11 @@ class ServeEngine:
         self._c_occ_sum.inc(self.pool.occupancy())
         if self.cache_kind == "paged":
             self._c_block_occ_sum.inc(self.pool.block_occupancy())
+            # each row attends over its length plus the token this step
+            # writes, clamped to the row's window: ceil(eff_len / bs)
+            self._c_attn_live_pages.inc(sum(
+                self.pool.blocks_needed(seq.cached_len + 1)
+                for seq in self.scheduler.running.values()))
             if self.pool.prefix_cache:
                 self._h_shared.observe(self.pool.blocks_shared)
         self._c_decode_steps.inc()

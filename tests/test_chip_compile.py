@@ -88,19 +88,26 @@ def test_qmm_compiles(spec, compiled_kernels, M, K, N, bits, path):
     assert names == {f"qmm_{path}_{bits}b"}
 
 
-@pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
-def test_paged_attention_compiles(spec, compiled_kernels, kv):
-    q = spec((SLOTS, 1, H, HD), jnp.bfloat16)
-    bt, ln = spec((SLOTS, NB), jnp.int32), spec((SLOTS,), jnp.int32)
+@pytest.mark.parametrize("kv,rows,nb,pool", [
+    pytest.param("bf16", SLOTS, NB, POOL, id="bf16"),
+    pytest.param("int8", SLOTS, NB, POOL, id="int8"),
+    pytest.param("int4", SLOTS, NB, POOL, id="int4"),
+    # glm4-chat's decode: 32 rows of 128 pages over a 4097-block pool
+    pytest.param("int8", 32, 128, 4097, id="int8-cell"),
+    pytest.param("int4", 32, 128, 4097, id="int4-cell"),
+])
+def test_paged_attention_compiles(spec, compiled_kernels, kv, rows, nb, pool):
+    q = spec((rows, 1, H, HD), jnp.bfloat16)
+    bt, ln = spec((rows, nb), jnp.int32), spec((rows,), jnp.int32)
     if kv == "bf16":
-        pool = spec((POOL, BLOCK, KV, HD), jnp.bfloat16)
-        names = compiled_kernels(ops.paged_attention, q, pool, pool, bt, ln)
+        pages = spec((pool, BLOCK, KV, HD), jnp.bfloat16)
+        names = compiled_kernels(ops.paged_attention, q, pages, pages, bt, ln)
         assert names == {"paged_decode_attention"}
         return
     dt, hds = (jnp.int8, HD) if kv == "int8" else (jnp.uint8, HD // 2)
-    pool = spec((POOL, BLOCK, KV, hds), dt)
-    sc = spec((POOL, BLOCK, KV), jnp.float32)
-    names = compiled_kernels(ops.paged_attention, q, pool, pool, bt, ln,
+    pages = spec((pool, BLOCK, KV, hds), dt)
+    sc = spec((pool, BLOCK, KV), jnp.float32)
+    names = compiled_kernels(ops.paged_attention, q, pages, pages, bt, ln,
                              sc, sc)
     assert names == {f"paged_decode_attention_{kv}"}
 

@@ -96,7 +96,11 @@ def test_qmm_dtypes(xdtype):
 
 @pytest.mark.parametrize("shape", [(3, 7, 4, 2, 1, 8),    # B,NB,bs,KV,G,hd
                                    (2, 9, 8, 1, 4, 16),
-                                   (4, 5, 16, 2, 2, 16)])
+                                   (4, 5, 16, 2, 2, 16),
+                                   # 16 pages a step: 3 steps, the last of 5
+                                   (3, 38, 16, 2, 2, 8),
+                                   # 8 pages a step: 5 full steps
+                                   (2, 41, 32, 1, 4, 16)])
 @pytest.mark.parametrize("kvdtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_kernel(shape, kvdtype):
     """Pallas paged decode attention (block-table index maps + online
@@ -112,6 +116,8 @@ def test_paged_attention_kernel(shape, kvdtype):
     bt = jnp.stack([1 + (jnp.arange(nb) + b) % (NB - 1) for b in range(B)])
     lengths = jnp.asarray([(7 * b + 3) % (nb * bs) + 1 for b in range(B)],
                           jnp.int32)
+    if nb * bs > 256:   # multi-step sweeps: rows into the second step on
+        lengths = lengths + jnp.asarray([256 * b for b in range(B)], jnp.int32)
     want = kref.paged_attention_ref(q, k, v, bt, lengths)
     got = paged_attention_pallas(q.reshape(B, KV, G, hd), k, v, bt, lengths,
                                  interpret=True).reshape(B, 1, KV * G, hd)
@@ -170,39 +176,86 @@ def _quant_pools(NB, bs, KV, hd, kv_bits, seed=11):
     return kc, vc, ks, vs, qmax
 
 
-@pytest.mark.parametrize("kv_bits", [8, 4])  # int8 codes / packed-int4 codes
-@pytest.mark.parametrize("case", ["block_boundary", "length_zero", "one_block"])
-def test_paged_attention_quant_edge_cases(kv_bits, case):
-    """Quantized-KV paged decode in interpret mode at the edges: length
-    exactly on a block boundary, all-masked length-0 garbage rows (zero
-    output, no NaN from the denominator guard), and an nb == 1 table."""
-    from repro.kernels.paged_attention import paged_attention_quant_pallas
+# Multi-step cases: 16-token pages give 16 pages a step, so nb = 37 sweeps
+# three steps of 16, 16 and 5 pages (nb not a multiple of the step).
+_STEP_BS, _STEP_NB = 16, 37
+_STEP_T = _STEP_NB * _STEP_BS
+_STEP_LENGTHS = {
+    "mid_step": [1, 100, 300, _STEP_T - 7],        # mid-page, mid-step
+    "step_boundary": [16, 256, 512, 48],           # on a page / a step end
+    "full": [_STEP_T, _STEP_T, _STEP_T - 1, 257],  # at nb x bs
+    "one_long": [_STEP_T, 1, 0, 3],                # one long, a dead row
+}
 
-    B, bs, KV, G, hd = 3, 4, 2, 2, 8
-    nb = 1 if case == "one_block" else 3
-    NB = 1 + B * nb
-    q = jnp.asarray(RNG.normal(size=(B, 1, KV * G, hd)), jnp.float32)
-    kc, vc, ks, vs, _ = _quant_pools(NB, bs, KV, hd, kv_bits)
-    bt = jnp.asarray(1 + np.arange(B * nb).reshape(B, nb), jnp.int32)
-    if case == "block_boundary":
-        lengths = jnp.asarray([bs, 2 * bs, nb * bs], jnp.int32)
-    elif case == "length_zero":
-        lengths = jnp.asarray([0, 0, bs + 1], jnp.int32)
-        bt = bt.at[0].set(0).at[1].set(0)  # dead rows sit on the garbage sink
+
+def test_pages_per_step_from_shapes():
+    """The pages a grid step folds in follow from the page shape, code
+    width and nb alone, and fit the VMEM budget: at glm4-9b's decode
+    shape (32 rows x 128 pages of 16 x 2 x 128) and phi3-mini's
+    (16 x 32 x 96), for bf16, int8 and nibble-packed int4 pages."""
+    from repro.kernels import paged_attention as pa
+
+    for kv, hd in ((2, 128), (32, 96)):
+        for dtype, hds in ((jnp.bfloat16, hd), (jnp.int8, hd),
+                           (jnp.uint8, hd // 2)):
+            P = pa.pages_per_step(16, kv, hds, dtype, 128)
+            assert 1 <= P <= 128 and P * 16 <= pa.TOKENS_PER_STEP
+            assert pa.step_vmem_bytes(P, 16, kv, hds, dtype) <= pa.VMEM_BUDGET
+            # the largest such P
+            assert (P * 16 == pa.TOKENS_PER_STEP or pa.step_vmem_bytes(
+                P + 1, 16, kv, hds, dtype) > pa.VMEM_BUDGET)
+    assert pa.pages_per_step(16, 2, 128, jnp.int8, 128) == 16   # glm4
+    assert pa.pages_per_step(16, 32, 96, jnp.int8, 128) < 16    # phi3-mini
+    assert pa.pages_per_step(16, 2, 128, jnp.int8, 5) == 5      # nb bound
+
+
+@pytest.mark.parametrize("kv_bits", [8, 4, 16])  # int8 / packed int4 / bf16
+@pytest.mark.parametrize("case", ["block_boundary", "length_zero", "one_block",
+                                  *_STEP_LENGTHS])
+def test_paged_attention_quant_edge_cases(kv_bits, case):
+    """Paged decode in interpret mode at the edges: length exactly on a
+    block boundary, all-masked length-0 garbage rows (zero output, no NaN
+    from the denominator guard), an nb == 1 table, and multi-page steps
+    whose lengths end mid-step, on a page or step boundary, at nb x bs,
+    or leave one long row beside short and dead ones."""
+    from repro.kernels.paged_attention import (paged_attention_pallas,
+                                               paged_attention_quant_pallas)
+
+    if case in _STEP_LENGTHS:
+        B, bs, KV, G, hd, nb = 4, _STEP_BS, 2, 2, 8, _STEP_NB
+        lengths = _STEP_LENGTHS[case]
     else:
-        lengths = jnp.asarray([1, bs // 2, bs], jnp.int32)
-    got = paged_attention_quant_pallas(
-        q.reshape(B, KV, G, hd), kc, vc, ks, vs, bt, lengths,
-        interpret=True).reshape(B, 1, KV * G, hd)
-    want = kref.quant_paged_attention_ref(q, kc, vc, ks, vs, bt, lengths)
+        B, bs, KV, G, hd = 3, 4, 2, 2, 8
+        nb = 1 if case == "one_block" else 3
+        lengths = {"block_boundary": [bs, 2 * bs, nb * bs],
+                   "length_zero": [0, 0, bs + 1],
+                   "one_block": [1, bs // 2, bs]}[case]
+    NB = 1 + B * nb
+    lengths = jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(RNG.normal(size=(B, 1, KV * G, hd)), jnp.float32)
+    bt = jnp.asarray(1 + np.arange(B * nb).reshape(B, nb), jnp.int32)
+    # dead rows sit on the garbage sink
+    bt = jnp.where((lengths == 0)[:, None], 0, bt)
+    if kv_bits == 16:
+        kc, vc = (jnp.asarray(RNG.normal(size=(NB, bs, KV, hd)), jnp.bfloat16)
+                  for _ in range(2))
+        got = paged_attention_pallas(q.reshape(B, KV, G, hd), kc, vc, bt,
+                                     lengths, interpret=True)
+        want = kref.paged_attention_ref(q, kc, vc, bt, lengths)
+    else:
+        kc, vc, ks, vs, _ = _quant_pools(NB, bs, KV, hd, kv_bits)
+        got = paged_attention_quant_pallas(
+            q.reshape(B, KV, G, hd), kc, vc, ks, vs, bt, lengths,
+            interpret=True)
+        want = kref.quant_paged_attention_ref(q, kc, vc, ks, vs, bt, lengths)
+    got = got.reshape(B, 1, KV * G, hd)
     assert not np.any(np.isnan(np.asarray(got)))
     live = np.asarray(lengths) > 0
-    if case == "length_zero":
-        # all-masked rows: the kernel's l == 0 guard yields exact zeros
-        # (the jnp oracle's masked softmax degenerates to a uniform
-        # average there — dead rows are never consumed, so only the
-        # no-NaN/zero contract matters, not oracle agreement)
-        np.testing.assert_array_equal(np.asarray(got[~live]), 0.0)
+    # all-masked rows: the kernel's l == 0 guard yields exact zeros (the
+    # jnp oracle's masked softmax degenerates to a uniform average there
+    # — dead rows are never consumed, so only the no-NaN/zero contract
+    # matters, not oracle agreement)
+    np.testing.assert_array_equal(np.asarray(got[~live]), 0.0)
     np.testing.assert_allclose(np.asarray(got[live]),
                                np.asarray(want, np.float32)[live],
                                rtol=2e-3, atol=2e-3)

@@ -190,3 +190,23 @@ def test_metrics_aggregate(served):
     assert m["tokens_total"] == 12 and m["tokens_per_s"] > 0
     assert 0.0 < m["mean_occupancy"] <= 1.0
     assert m["decode_steps"] <= m["steps"]
+
+
+def test_attn_live_pages_counts_each_rows_pages(served):
+    """``serve.attn_live_pages`` grows by ``ceil(len / bs)`` per running
+    row each decode step, ``len`` counting the token the step writes."""
+    cfg, model, sparams, fns = served
+    bs = 4
+    eng = ServeEngine(model, sparams, num_slots=3, max_len=32,
+                      block_size=bs, decode_fn=fns["decode_fn"])
+    for n, s in ((3, 1), (8, 2), (13, 3)):
+        eng.submit(_prompt(cfg, n, s), max_new_tokens=12)
+    eng.step()                                   # admits all three
+    live = eng.obs.get("serve.attn_live_pages")
+    for _ in range(5):
+        rows = list(eng.scheduler.running.values())
+        assert len(rows) == 3
+        want = live.value + sum(-(-min(q.cached_len + 1, 32) // bs)
+                                for q in rows)
+        eng.step()
+        assert live.value == want
