@@ -73,8 +73,8 @@ def save_fixture(red: dict, path: str, seconds: float) -> None:
     hi = lo + int(seconds * 1e9)
     keep = lambda evs: [e for e in evs if e[1] < hi and e[1] + e[2] > lo]
     Path(path).write_text(json.dumps({
-        "ops": keep(red["ops"]), "modules": keep(red["modules"]),
-        "host": keep(red["host"]), "window": [lo, hi]}))
+        **{k: keep(red[k]) for k in ("ops", "modules", "host", "spans", "scoped")},
+        "window": [lo, hi]}))
 
 
 def main() -> int:
@@ -90,6 +90,7 @@ def main() -> int:
     sys.path.insert(0, str(HERE.parent))
     sys.path.insert(0, str(ROOT / "src"))
     from chip import harness, reference
+    from chip import spans as spansmod
     from chip import trace as tracemod
     from chip import traffic as trafficmod
 
@@ -115,7 +116,8 @@ def main() -> int:
             trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         w0, close, _ = harness.measure(loop, args.seconds, trace_dir)
         if trace_dir:
-            save_fixture(tracemod.load(trace_dir), args.trace_out, 0.3)
+            save_fixture({**tracemod.load(trace_dir), **spansmod.load(trace_dir)},
+                         args.trace_out, 0.3)
         harness.drain(loop, w0, close)
         samples = harness.judged_samples(loop, w0, seed)
         del loop, eng, recorder
@@ -126,6 +128,7 @@ def main() -> int:
         judged = reference.logit_gaps(
             cfg, seed, [(p, s, {"served": np.asarray(s), "control": np.asarray(c)})
                         for (p, s), c in zip(samples, ctrl)],
+            layer_fn=harness.family(cfg).reference_layer,
             shape=(harness.MAX_SAMPLES, cfg["engine"]["max_len"]))
         flips = sum(int(np.sum(np.asarray(c) != np.asarray(s)))
                     for (_, s), c in zip(samples, ctrl))

@@ -8,15 +8,22 @@ metric is found by its name in ``BENCHMARK.json``:
   engine settings) and is served through the program's own entry points:
   ``build_model``, ``quantize_for_serving`` at ``policy_for``'s policy,
   ``ServeEngine.submit`` / ``step``;
+- the program's family of that configuration (``ModelConfig.family``) is
+  ``families/<family>.py``: its weights from the seed, the reference's
+  decoder layer and the costs of its work (``FAMILY_API``);
 - a traffic mix is ``traffic/<name>.json``, read by ``traffic.py``;
 - a per-layer metric is ``metrics/<name>.py``, whose ``read(ctx)`` returns
-  a number or ``None`` (nothing to read, and the metric is left out);
+  a number or ``None`` (nothing to read, and the metric is left out); the
+  context (``_context``) holds the family's costs, the window's delta of
+  every counter of the engine's registry and the reduced trace, with every
+  span and named scope of the program;
 - the limits of a cell's comparison are ``limits/<workload>.json``: the
   gap statistics of ``gap_stats`` it compares, each with its limit.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -28,15 +35,22 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from . import costs
 from . import peaks
 from . import reference
+from . import spans as spansmod
 from . import trace as tracemod
 from . import traffic as trafficmod
-from . import weights
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+FAMILIES = HERE / "families"
+# what a family module provides: the seed's weights in the program's
+# layout, the reference's decoder layer (``reference.logit_gaps``'s
+# ``layer_fn``), and the operations and bytes of the work (``costs.py``)
+FAMILY_API = ("program_params", "reference_layer", "prefill_chunk_flops",
+              "decode_step_flops", "decode_attn_bytes", "decode_attn_flops",
+              "decode_qmm_calls", "prefill_qmm_calls", "kv_bytes_per_token",
+              "qmm_least_seconds")
 HOST_SLACK_S = 60.0       # how long after the close a submitted request may
                           # still wait for its first token before it fails
 MAX_SAMPLES = 8           # finished greedy requests judged by the reference
@@ -67,6 +81,34 @@ def load_limits(workload: str) -> dict:
     return json.loads((HERE / "limits" / f"{workload}.json").read_text())
 
 
+@functools.cache
+def _family_module(path: Path):
+    # loaded as a module of this package's ``families``, so that its
+    # relative imports reach the shared modules
+    spec = importlib.util.spec_from_file_location(
+        f"{__package__}.families.{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [n for n in FAMILY_API if not hasattr(mod, n)]
+    if missing:
+        raise AttributeError(f"{path} does not define {missing}")
+    return mod
+
+
+def family(cfg: dict):
+    """The module of the program's family of the configuration ``cfg``:
+    ``families/<ModelConfig.family>.py``."""
+    from repro.configs import get_config
+
+    name = get_config(cfg["program_config"]).family
+    path = FAMILIES / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"{cfg['name']}: the program's family {name!r} has no module "
+            f"{path}")
+    return _family_module(path)
+
+
 def metric_reader(name: str):
     path = HERE / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"chip_metric_{name}", path)
@@ -95,17 +137,18 @@ def chips(count: int):
 
 # ------------------------------------------------------------------ build
 def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs import get_config
+    """The program's ``ModelConfig`` for a configuration file: the named
+    ``program_config`` with every key of the file that is a field of it
+    (lists as tuples).  The file's ``name`` is the benchmark's own."""
+    from repro.configs import ModelConfig, get_config
 
-    keys = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
-            "d_ff", "vocab_size", "rope_theta", "norm_eps", "act",
-            "tie_embeddings", "dtype")
-    pcfg = dataclasses.replace(get_config(cfg["program_config"]),
-                               **{k: cfg[k] for k in keys})
-    if pcfg.hd != cfg["head_dim"] or pcfg.family != "dense":
-        raise ValueError(f"{cfg['name']}: not a dense model with head_dim "
-                         f"{cfg['head_dim']}")
+    fields = {f.name for f in dataclasses.fields(ModelConfig)} - {"name"}
+    pcfg = dataclasses.replace(get_config(cfg["program_config"]), **{
+        k: tuple(v) if isinstance(v, list) else v
+        for k, v in cfg.items() if k in fields})
+    if pcfg.hd != cfg["head_dim"]:
+        raise ValueError(f"{cfg['name']}: the program's head_dim is "
+                         f"{pcfg.hd}, the file's {cfg['head_dim']}")
     return pcfg
 
 
@@ -118,6 +161,7 @@ def build(cfg: dict, seed: int):
     from repro.quant.qat import policy_for
     from repro.train.serve import quantize_for_serving
 
+    fam = family(cfg)
     model = build_model(program_config(cfg))
     pol = cfg["policy"]
     policy = policy_for(model, default_bits=pol["weight_bits"])
@@ -126,7 +170,7 @@ def build(cfg: dict, seed: int):
     if policy.frozen != frozen:
         raise ValueError(f"the program freezes {policy.frozen}, the "
                          f"configuration states {frozen}")
-    params = weights.program_params(cfg, seed, model)
+    params = fam.program_params(cfg, seed, model)
     sparams = quantize_for_serving(model, params, policy)
     del params
     jax.block_until_ready(sparams)
@@ -392,6 +436,7 @@ def judge(cfg: dict, seed: int, samples) -> dict:
     """The reference's judgement of the served greedy tokens."""
     judged = reference.logit_gaps(
         cfg, seed, [(p, s, {"served": np.asarray(s)}) for p, s in samples],
+        layer_fn=family(cfg).reference_layer,
         shape=(MAX_SAMPLES, cfg["engine"]["max_len"]))
     return gap_stats([j["served"] for j in judged])
 
@@ -443,7 +488,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                   "count": len(devs), "memory_peak_bytes": peak}
         result = {"attempted": attempted, "failed": failed}
         if trace:
-            red = tracemod.load(trace_dir)
+            red = {**tracemod.load(trace_dir), **spansmod.load(trace_dir)}
             ctx = _context(cfg, mix_spec, dev, recorder, t0, t_close,
                            traced, red, before, after)
             result["metrics"] = {}
@@ -500,27 +545,40 @@ def _program_fns(model):
 
 
 def _counters(eng) -> dict:
+    """``counts``: four counts the harness takes from the engine; ``obs``:
+    every counter of the engine's ``repro.obs`` registry, by name."""
     m = eng.metrics()
-    return {"decode_steps": m["decode_steps"],
-            "bubbles": m["pipeline"]["bubbles"],
-            "prefix_hit_tokens": eng.pool.prefix_hit_tokens,
-            "admitted_tokens": sum(len(r.prompt) for r in eng.requests.values()
-                                   if r.first_token_step is not None)}
+    return {
+        "counts": {"decode_steps": m["decode_steps"],
+                   "bubbles": m["pipeline"]["bubbles"],
+                   "prefix_hit_tokens": eng.pool.prefix_hit_tokens,
+                   "admitted_tokens": sum(
+                       len(r.prompt) for r in eng.requests.values()
+                       if r.first_token_step is not None)},
+        "obs": {name: inst["value"] for name, inst in eng.obs.snapshot().items()
+                if inst["type"] == "counter"}}
+
+
+def _delta(before: dict, after: dict) -> dict:
+    return {k: v - before.get(k, 0) for k, v in after.items()}
 
 
 def _context(cfg, mix_spec, dev, recorder, t0, t_close, traced, red,
              before, after):
-    """What a per-layer metric may read (``metrics/*.py``)."""
+    """What a per-layer metric may read (``metrics/*.py``).  ``before`` and
+    ``after`` are ``_counters`` at the window's two ends."""
     from repro.serve.sampler import sample_rows
 
+    costs = family(cfg)
     pre_w, dec_w = recorder.between(t0, t_close)
     pre_t, dec_t = recorder.between(*traced)
     window_flops = (sum(costs.prefill_chunk_flops(cfg, s, v) for s, v in pre_w)
                     + sum(costs.decode_step_flops(cfg, ls) for ls in dec_w))
     return SimpleNamespace(
         cfg=cfg, traffic=mix_spec, peak=peaks.peak_for(dev.device_kind),
-        trace=red, costs=costs, tracemod=tracemod,
+        trace=red, costs=costs, tracemod=tracemod, spans=spansmod,
         modules=dict(recorder.names, sampler=sample_rows.__name__),
-        counts={k: after[k] - before[k] for k in before},
+        counts=_delta(before["counts"], after["counts"]),
+        obs=_delta(before["obs"], after["obs"]),
         traced_prefill=pre_t, traced_decode=dec_t,
         window_s=t_close - t0, window_flops=window_flops)

@@ -5,13 +5,12 @@ allocations and evictions) or ``admit`` spans (each admission's prefill
 dispatches, logits fetch and first-token choice) was open.  The spans are
 the program's own annotations, on the profiler's clock.  Layer: engine.
 Moves ``itl_p95_ms``."""
-import importlib
 
 
 def read(ctx):
-    spans = importlib.import_module(ctx.tracemod.__package__ + ".spans")
-    trace = spans.attach(ctx.trace)
-    if not trace.get("spans"):
+    # a program that opens no ``serve.step`` span around its steps has
+    # none of the engine's spans: nothing to read
+    if not any(n == "serve.step" for n, _, _ in ctx.trace.get("spans", ())):
         return None
-    return (100.0 * spans.idle_under(trace, *spans.ADMIT_SPANS)
-            / ctx.tracemod.window_s(trace))
+    return (100.0 * ctx.spans.idle_under(ctx.trace, *ctx.spans.ADMIT_SPANS)
+            / ctx.tracemod.window_s(ctx.trace))

@@ -2,13 +2,10 @@
 model's ``lm_head`` scope that run inside the decode executions, summed,
 over the number of decode executions.  Layer: decode.  Moves
 ``tokens_per_s``."""
-import importlib
 
 
 def read(ctx):
-    spans = importlib.import_module(ctx.tracemod.__package__ + ".spans")
-    trace = spans.attach(ctx.trace)
-    dec = ctx.tracemod.modules(trace, ctx.modules["decode"])
-    if not dec or not any(n == "lm_head" for n, _, _ in trace.get("scoped", ())):
+    dec = ctx.tracemod.modules(ctx.trace, ctx.modules["decode"])
+    if not dec or not any(n == "lm_head" for n, _, _ in ctx.trace.get("scoped", ())):
         return None
-    return 1e3 * spans.scoped_within(trace, dec, "lm_head") / len(dec)
+    return 1e3 * ctx.spans.scoped_within(ctx.trace, dec, "lm_head") / len(dec)

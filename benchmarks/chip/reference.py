@@ -7,10 +7,13 @@ run's seed (``weights.py``), quantizes them as the configuration states
 at ``weight_bits``, the embedding and the head at ``frozen_bits``), keeps
 K and V as ``kv_bits`` codes with one max-abs scale per token and head, and
 runs a straightforward causal forward in float32 with
-``default_matmul_precision("highest")``: RMSNorm, rotary embedding (the
-split-half convention), grouped-query attention and a SwiGLU MLP.  It
-runs layer by layer over a block of whole sequences, and reads the logits
-in blocks of rows, so that it fits beside nothing else on the chip.
+``default_matmul_precision("highest")``.  The block-wise forward loop here
+(``logits``, ``logit_gaps``) embeds, runs a family's decoder layer
+(``layer_fn``: the ``reference_layer`` of ``families/<family>.py``) layer
+by layer over a block of whole sequences, and reads the logits in blocks
+of rows, so that it fits beside nothing else on the chip.  The dense
+family's layer is ``layer``: RMSNorm, rotary embedding (the split-half
+convention), grouped-query attention and a SwiGLU MLP.
 
 What it compares: a served token is judged by how far the reference's
 logit for it lies below the reference's best logit at that position
@@ -95,8 +98,13 @@ def _layer(cfg, h, lw):
     return h + (jax.nn.silu(x @ w["wg"]) * (x @ w["wu"])) @ w["wd"]
 
 
+def layer(cfg, key, l, h):
+    """Dense decoder layer ``l`` of the seed's weights over h (B, T, D)."""
+    return _layer(cfg, h, W.layer(cfg, key, l))
+
+
 @functools.lru_cache(maxsize=None)
-def _jits(cfg_json: str):
+def _jits(cfg_json: str, layer_fn):
     cfg = json.loads(cfg_json)
 
     @jax.jit
@@ -106,7 +114,7 @@ def _jits(cfg_json: str):
 
     @jax.jit
     def layer(key, l, h):
-        return _layer(cfg, h, W.layer(cfg, key, l))
+        return layer_fn(cfg, key, l, h)
 
     @jax.jit
     def head(key, h, judged):
@@ -121,26 +129,26 @@ def _jits(cfg_json: str):
     return embed, layer, head
 
 
-def _hidden(cfg, key, toks):
+def _hidden(cfg, key, toks, layer_fn):
     """Final hidden states (B, T, D) of token blocks ``toks`` (B, T)."""
-    embed, layer, _ = _jits(json.dumps(cfg, sort_keys=True))
+    embed, layer, _ = _jits(json.dumps(cfg, sort_keys=True), layer_fn)
     h = embed(key, jnp.asarray(toks))
     for l in range(cfg["num_layers"]):
         h = layer(key, l, h)
     return h
 
 
-def logits(cfg, seed: int, tokens):
+def logits(cfg, seed: int, tokens, layer_fn=layer):
     """Full logits (T, V) of one sequence: for small sizes and tests."""
     key = W.seed_key(seed)
     with jax.default_matmul_precision("highest"):
-        h = _hidden(cfg, key, np.asarray(tokens, np.int32)[None])[0]
+        h = _hidden(cfg, key, np.asarray(tokens, np.int32)[None], layer_fn)[0]
         x = rms_norm(h, W.final_norm(cfg, key), cfg["norm_eps"])
         w = wrpn_dequant(W.lm_head(cfg, key), cfg["policy"]["frozen_bits"])
         return np.asarray(x @ w)
 
 
-def logit_gaps(cfg, seed: int, samples, *, rows: int = 256,
+def logit_gaps(cfg, seed: int, samples, *, layer_fn=layer, rows: int = 256,
                shape: tuple[int, int] | None = None):
     """The reference's judgement of served tokens.
 
@@ -151,9 +159,10 @@ def logit_gaps(cfg, seed: int, samples, *, rows: int = 256,
     first).  Returns, per sample, ``{label: gaps}``: at each position, the
     reference's best logit minus its logit for the judged token.
     ``shape`` pads the block of sequences to (sequences, tokens), so that
-    every call of a cell runs the same compiled programs.
+    every call of a cell runs the same compiled programs.  ``layer_fn``
+    is the family's decoder layer, ``(cfg, key, l, h) -> h``.
     """
-    head = _jits(json.dumps(cfg, sort_keys=True))[2]
+    head = _jits(json.dumps(cfg, sort_keys=True), layer_fn)[2]
     key = W.seed_key(seed)
     labels = sorted({lab for _, _, j in samples for lab in j})
     lens = [len(p) + len(s) - 1 for p, s, _ in samples]
@@ -166,7 +175,7 @@ def logit_gaps(cfg, seed: int, samples, *, rows: int = 256,
         for c, lab in enumerate(labels):
             judged[i, len(p) - 1:len(p) - 1 + len(s), c] = j[lab]
     with jax.default_matmul_precision("highest"):
-        h = _hidden(cfg, key, toks)
+        h = _hidden(cfg, key, toks, layer_fn)
         flat = h.reshape(-1, h.shape[-1])
         jflat = judged.reshape(-1, len(labels))
         gaps = np.concatenate([

@@ -2,56 +2,45 @@
 
 The engine's ``repro.obs`` tracer opens a ``jax.profiler.TraceAnnotation``
 for each of its spans whenever a profiler session records, and the model
-names two device costs with ``jax.named_scope`` (``embed_quant``,
-``lm_head``).  ``load`` reduces a trace to those: the program's spans as
-``[name, start_ns, dur_ns]`` (host events named in ``PROGRAM_SPANS``) and
-the device operations under a scope as ``[scope, start_ns, dur_ns]``, on
-the one clock the profiler gives the device's operations and the host's
-annotations.  The reduction below works on those lists and on
+names device work with ``jax.named_scope`` (``embed_quant``, ``lm_head``).
+``load`` reduces a trace to those, whatever their names: as ``spans``,
+every host event ``[name, start_ns, dur_ns]`` on the thread that opened
+the harness's window (the serving thread: the program's spans, and the
+runtime's own events under them, such as ``PjitFunction(step)``), the
+harness's own annotations left out; as ``scoped``, each device operation
+once under each named scope of its path, ``[scope, start_ns, dur_ns]``.
+Both lie on the one clock the profiler gives the device's operations and
+the host's annotations.  The reduction below works on those lists and on
 ``trace.load``'s, so a recorded trace can be kept as a JSON fixture.
 
-An operation's scope is in the ``tf_op`` stat of its *event metadata*
+An operation's scopes are in the ``tf_op`` stat of its *event metadata*
 (``jit(step)/embed_quant/reduce_max:`` on a TPU v5e), which
 ``jax.profiler.ProfileData`` does not expose; ``_scoped_ops`` reads it
 from the ``XSpace`` protobuf with the few fields it needs declared here.
-
-``attach`` adds the two lists to a reduction that ``trace.load`` made,
-reading them from the same profile: the newest in the harness's temporary
-directory, while the harness still holds it, if its traced window is the
-reduction's.
 """
 from __future__ import annotations
 
 import functools
 import glob
 import os
-import tempfile
 
 from . import trace as tracemod
 
-# the engine's spans (serve/engine.py, docs/metrics.md), outermost first
-PROGRAM_SPANS = ("serve.step", "admit.schedule", "admit", "prefill.chunk",
-                 "prefill.full", "admit.fetch", "admit.sample",
-                 "decode.reserve", "decode.step", "decode.dispatch",
-                 "decode.wait", "decode.host")
 # the spans of the admission path: the scheduler's pop of queued requests
 # (prefix-trie lookup, block allocation and eviction) and each admission
 ADMIT_SPANS = ("admit.schedule", "admit")
-SCOPES = ("embed_quant", "lm_head")
 # the event-metadata stat of an "XLA Ops" event that holds the HLO op's
 # framework name: the path of named scopes it was traced under
 SCOPE_STAT = "tf_op"
-TRACE_DIR_PREFIX = "chipbench-trace-"      # the harness's trace directories
 
 
-def scope_of(framework_name: str) -> str | None:
-    """The scope of ``SCOPES`` that a framework name lies under:
-    ``jit(step)/jit(main)/embed_quant/abs`` is ``embed_quant``."""
-    parts = framework_name.split("/")
-    for scope in SCOPES:
-        if scope in parts:
-            return scope
-    return None
+def scopes_of(framework_name: str) -> list[str]:
+    """The named scopes a framework name lies under, outermost first: its
+    path's components but the last (the operation) and those that name a
+    transformation (``jit(step)``).  ``jit(step)/jit(main)/embed_quant/abs``
+    lies under ``embed_quant``."""
+    parts = framework_name.split("/")[:-1]
+    return list(dict.fromkeys(p for p in parts if p and "(" not in p))
 
 
 def _trace_file(logdir: str) -> str:
@@ -111,9 +100,9 @@ def _xspace():
 
 def _scoped_ops(path: str, device_plane: str) -> list[list]:
     """``[scope, start_ns, dur_ns]`` of the "XLA Ops" events on
-    ``device_plane`` whose framework name lies under one of ``SCOPES``,
-    timed as ``jax.profiler.ProfileData`` times them (whole ns, rounded
-    down)."""
+    ``device_plane``, once for each named scope their framework name lies
+    under, timed as ``jax.profiler.ProfileData`` times them (whole ns,
+    rounded down)."""
     space = _xspace()()
     with open(path, "rb") as f:
         space.ParseFromString(f.read())
@@ -122,23 +111,22 @@ def _scoped_ops(path: str, device_plane: str) -> list[list]:
         if plane.name != device_plane:
             continue
         stat_names = {e.key: e.value.name for e in plane.stat_metadata}
-        scope_of_md = {}
+        scopes_of_md = {}
         for e in plane.event_metadata:
             for st in e.value.stats:
                 if stat_names.get(st.metadata_id) == SCOPE_STAT:
-                    scope = scope_of(st.str_value
-                                     or stat_names.get(st.ref_value, ""))
-                    if scope:
-                        scope_of_md[e.key] = scope
+                    scopes = scopes_of(st.str_value
+                                       or stat_names.get(st.ref_value, ""))
+                    if scopes:
+                        scopes_of_md[e.key] = scopes
                     break
         for line in plane.lines:
             if line.name != "XLA Ops":
                 continue
             for ev in line.events:
-                scope = scope_of_md.get(ev.metadata_id)
-                if scope:
-                    out.append([scope, line.timestamp_ns + ev.offset_ps // 1000,
-                                ev.duration_ps // 1000])
+                start = line.timestamp_ns + ev.offset_ps // 1000
+                for scope in scopes_of_md.get(ev.metadata_id, ()):
+                    out.append([scope, start, ev.duration_ps // 1000])
     return out
 
 
@@ -150,35 +138,19 @@ def load(logdir: str, device_index: int = 0) -> dict:
     path = _trace_file(logdir)
     out = {"window": None, "spans": [],
            "scoped": _scoped_ops(path, f"/device:TPU:{device_index}")}
+    harness_spans = set(tracemod.HOST_SPANS) | {tracemod.WINDOW_SPAN}
     for plane in jax.profiler.ProfileData.from_file(path).planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name in PROGRAM_SPANS:
-                        out["spans"].append([e.name, e.start_ns, e.duration_ns])
-                    elif e.name == tracemod.WINDOW_SPAN:
-                        out["window"] = [e.start_ns, e.start_ns + e.duration_ns]
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            events = [[e.name, e.start_ns, e.duration_ns] for e in line.events]
+            window = [e for e in events if e[0] == tracemod.WINDOW_SPAN]
+            if window:           # the serving thread
+                out["window"] = [window[0][1], window[0][1] + window[0][2]]
+                out["spans"] += [e for e in events if e[0] not in harness_spans]
     for key in ("spans", "scoped"):
         out[key].sort(key=lambda e: e[1])
     return out
-
-
-def attach(trace: dict) -> dict:
-    """``trace`` (a ``trace.load`` reduction) with ``spans`` and ``scoped``
-    added from the profile it was reduced from: the newest of the harness's
-    trace directories, if its ``bench.window`` is ``trace["window"]``.
-    Left as it is when it holds them already, or when the newest profile
-    is another.  A stopgap until ``trace.load`` fills both keys itself."""
-    if "spans" in trace and "scoped" in trace:
-        return trace
-    pattern = os.path.join(tempfile.gettempdir(), TRACE_DIR_PREFIX + "*",
-                           "**", "*.xplane.pb")
-    paths = glob.glob(pattern, recursive=True)
-    if paths:
-        red = load(os.path.dirname(max(paths, key=os.path.getmtime)))
-        if red["window"] == trace["window"]:
-            trace["spans"], trace["scoped"] = red["spans"], red["scoped"]
-    return trace
 
 
 def idle_intervals(trace) -> list[tuple[float, float]]:
@@ -219,9 +191,8 @@ def idle_under(trace, *names: str) -> float:
 
 
 def innermost(trace) -> list[tuple[float, float, str]]:
-    """The window cut into stretches, each named by the innermost program
-    span open over it (the engine's spans nest on its one thread), or
-    ``none``."""
+    """The window cut into stretches, each named by the innermost span open
+    over it (the serving thread's events nest), or ``none``."""
     lo, hi = trace["window"]
     segs, stack, t = [], [], lo      # stack: (end, name), innermost last
 
@@ -248,7 +219,7 @@ def innermost(trace) -> list[tuple[float, float, str]]:
 
 def idle_by_innermost(trace, within: str | None = None) -> dict[str, float]:
     """Seconds of device idle time in the window, each charged to the
-    innermost program span open over it (``none`` where none was open);
+    innermost span open over it (``none`` where none was open);
     with ``within``, only idle time while that span or harness
     annotation was open."""
     idle = idle_intervals(trace)
